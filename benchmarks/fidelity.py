@@ -260,17 +260,15 @@ def prefill_backends() -> List[Row]:
     lengths = jnp.full((b,), s, jnp.int32)
     rows: List[Row] = []
 
-    us = timeit(lambda: flash_attention(q, k, v, causal=True,
-                                        interpret=True), iters=3)
+    us = timeit(lambda: flash_attention(q, k, v, causal=True), iters=3)
     err = float(jnp.max(jnp.abs(
-        flash_attention(q, k, v, causal=True, interpret=True)
+        flash_attention(q, k, v, causal=True)
         - flash_attention_ref(q, k, v, causal=True))))
     rows.append(("prefill/flash_vs_dense", us, f"max_abs_err={err:.2e}"))
 
     for kr in (0.5, 0.75, 1.0):
         fn = lambda: aqua_prefill(q, k, v, lengths, k_ratio=kr,  # noqa: E731
-                                  block_dims=8, q_blk=32, k_blk=32,
-                                  interpret=True)
+                                  block_dims=8, q_blk=32, k_blk=32)
         us = timeit(fn, iters=3)
         k_dims = round_k_dims(d, kr, 8)
         bi = chunk_topk_block_indices(q, k_dims, 8, 32, lengths)
@@ -289,7 +287,6 @@ def prefill_backends() -> List[Row]:
     # kernel; max_abs_err gates that. Skipped (loudly) below 4 devices —
     # CI runs under XLA_FLAGS=--xla_force_host_platform_device_count=8.
     if jax.device_count() >= 4:
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.launch.mesh import make_serving_mesh
         mesh = make_serving_mesh((2, 2))
@@ -300,14 +297,14 @@ def prefill_backends() -> List[Row]:
 
         def core(qs, ks_, vs, ls):
             return aqua_prefill(qs, ks_, vs, ls, k_ratio=0.5, block_dims=8,
-                                q_blk=32, k_blk=32, interpret=True)
+                                q_blk=32, k_blk=32)
 
-        meshed = jax.jit(shard_map(
+        meshed = jax.jit(jax.shard_map(
             core, mesh=mesh,
             in_specs=(P("data", "model", None, None),
                       P("data", "model", None, None),
                       P("data", "model", None, None), P("data")),
-            out_specs=P("data", "model", None, None), check_rep=False))
+            out_specs=P("data", "model", None, None), check_vma=False))
         us = timeit(lambda: meshed(qb, kb, vb, lb), iters=3)
         err = float(jnp.max(jnp.abs(meshed(qb, kb, vb, lb)
                                     - core(qb, kb, vb, lb))))
@@ -774,6 +771,7 @@ def longcontext_bench() -> List[Row]:
     from repro.core import selection
     from repro.core.calibration import identity_projections
     from repro.kernels.ops import aqua_decode, aqua_paged_decode, block_counts
+    from repro.launch.mesh import chip_peaks
     from repro.serving import ContinuousBatchingEngine, poisson_trace
 
     rows: List[Row] = []
@@ -784,7 +782,8 @@ def longcontext_bench() -> List[Row]:
     nb, nb_sel = block_counts(d, kr, bd)
     dim_frac = nb_sel / nb               # fraction of khat dims streamed
     q_blk = k_blk = 256                  # prefill kernel tiling
-    hbm_gbps = 819e9                     # nominal HBM bandwidth (bytes/s)
+    # nominal HBM bandwidth (bytes/s) of the chip these geometries target
+    hbm_gbps = chip_peaks("TPU v5 lite").hbm_bw
 
     for s in (32768, 65536):
         tag = f"{s // 1024}k"

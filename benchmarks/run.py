@@ -95,4 +95,7 @@ def main(argv=None) -> None:
 
 
 if __name__ == "__main__":
+    from repro import runtime_flags
+
+    runtime_flags.configure_compile_cache()
     main()

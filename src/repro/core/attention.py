@@ -26,8 +26,9 @@ the chunked online-softmax scan for long sequences), ``flash`` (Pallas
 flash kernel), ``aqua-masked-dense`` (jnp reference for AQUA),
 ``aqua-block-sparse`` (Pallas chunked-prefill + decode kernels streaming
 only the selected dim-blocks). ``auto`` resolves to kernels on TPU and
-jnp references elsewhere; kernel backends fall back to the masked-dense
-reference when Pallas is unavailable (``runtime_flags.PALLAS_OVERRIDE``).
+jnp references elsewhere; off-TPU, kernel backends fall back to the
+masked-dense reference when Pallas is unavailable
+(``runtime_flags.PALLAS_OVERRIDE``).
 
 Conventions:
   x            (B, S, d_model)
@@ -368,8 +369,6 @@ def _shard_mapped_decode_core(mesh, qq, k, v, positions, count, *,
     lanes (batch) over the data axes, KV heads over ``model``, softmax
     axis intact per shard. Falls back to the plain core when neither axis
     divides its mesh extent (the specs sanitize to fully-replicated)."""
-    from jax.experimental.shard_map import shard_map
-
     from repro.distributed import sharding as dsh
 
     b, kvh = qq.shape[0], qq.shape[1]
@@ -383,11 +382,11 @@ def _shard_mapped_decode_core(mesh, qq, k, v, positions, count, *,
         return core(qq, k, v, positions, count)
     P = jax.sharding.PartitionSpec
     head4 = P(batch_ax, kv_ax, None, None)
-    return shard_map(
+    return jax.shard_map(
         core, mesh=mesh,
         in_specs=(head4, head4, head4, P(batch_ax, None), P(batch_ax)),
         out_specs=(head4, head4),
-        check_rep=False,
+        check_vma=False,
     )(qq, k, v, positions, count)
 
 
@@ -428,8 +427,6 @@ def shard_mapped_prefill_kernel(mesh, backend, qq, kk, v, *, cfg, aqua,
     layout; ``positions`` must be 1-D (2-D tables route to the dense
     reference before dispatch). Returns (out (B, S, KV, G, Dv), None) —
     kernel backends produce no dense weights."""
-    from jax.experimental.shard_map import shard_map
-
     b, s, kvh = qq.shape[0], qq.shape[1], qq.shape[2]
     batch_ax, kv_ax = _kernel_row_axes(mesh, b, kvh)
     if lengths is None:
@@ -445,14 +442,14 @@ def shard_mapped_prefill_kernel(mesh, backend, qq, kk, v, *, cfg, aqua,
     # pallas_call in the jitted step would face the SPMD partitioner —
     # the exact hazard this wrapper exists to remove.
     P = jax.sharding.PartitionSpec
-    out = shard_map(
+    out = jax.shard_map(
         core, mesh=mesh,
         in_specs=(P(batch_ax, None, kv_ax, None, None),
                   P(batch_ax, None, kv_ax, None),
                   P(batch_ax, None, kv_ax, None),
                   P(None), P(batch_ax)),
         out_specs=P(batch_ax, None, kv_ax, None, None),
-        check_rep=False,
+        check_vma=False,
     )(qq, kk, v, positions, lengths)
     return out, None
 
@@ -463,8 +460,6 @@ def shard_mapped_decode_kernel(mesh, backend, q, cache, *, cfg, aqua):
     axis stays whole per shard (the kernel streams full dim-major sequence
     stripes), so per-shard ``NB_sel``/``NB_total`` accounting equals the
     global one. Returns (B, KV, G, Dv)."""
-    from jax.experimental.shard_map import shard_map
-
     b, kvh = q.shape[0], q.shape[1]
     batch_ax, kv_ax = _kernel_row_axes(mesh, b, kvh)
 
@@ -475,12 +470,12 @@ def shard_mapped_decode_kernel(mesh, backend, q, cache, *, cfg, aqua):
 
     P = jax.sharding.PartitionSpec
     head4 = P(batch_ax, kv_ax, None, None)
-    return shard_map(
+    return jax.shard_map(
         core, mesh=mesh,
         in_specs=(head4, head4, head4, P(batch_ax, None), P(batch_ax),
                   P(batch_ax, kv_ax, None)),
         out_specs=head4,
-        check_rep=False,
+        check_vma=False,
     )(q, cache.k, cache.v, cache.positions, cache.count, cache.acc_score)
 
 
@@ -511,8 +506,6 @@ def shard_mapped_paged_decode_kernel(mesh, backend, q, cache, *, cfg, aqua,
     (``distributed.sharding.page_rank_pspec``) and its entries are
     per-lane logical indices, so each shard's kernel invocation
     scalar-prefetches its own lane group's rows unchanged."""
-    from jax.experimental.shard_map import shard_map
-
     b, kvh = q.shape[0], q.shape[1]
     batch_ax, kv_ax = _kernel_row_axes(mesh, b, kvh)
 
@@ -549,11 +542,11 @@ def shard_mapped_paged_decode_kernel(mesh, backend, q, cache, *, cfg, aqua,
         return backend.paged_decode(qs, local, cfg=cfg, aqua=aqua,
                                     part_idx=part)
 
-    return shard_map(
+    return jax.shard_map(
         core, mesh=mesh,
         in_specs=tuple(in_specs),
         out_specs=head4,
-        check_rep=False,
+        check_vma=False,
     )(*operands)
 
 
@@ -721,8 +714,9 @@ def resolve_backend(name: str = "auto",
     TPU, or forced via ``runtime_flags.PALLAS_OVERRIDE``) and the jnp
     references otherwise. Explicitly requested kernel backends run in
     interpret mode off-TPU, but fall back to the masked-dense reference
-    when Pallas is unavailable; AQUA-native backends fall back to flash /
-    dense when AQUA is disabled (no projections to select over).
+    when Pallas is unavailable there (on a TPU that raises instead);
+    AQUA-native backends fall back to flash / dense when AQUA is disabled
+    (no projections to select over).
     """
     aqua_on = aqua is not None and aqua.enabled
     if name in (None, "", "auto"):
@@ -732,6 +726,12 @@ def resolve_backend(name: str = "auto",
             name = "aqua-masked-dense" if aqua_on else "dense-jnp"
     be = get_backend(name)
     if be.requires_pallas and not _rtf.pallas_available():
+        if _rtf.on_tpu():
+            # a TPU serving the jnp reference in place of a requested
+            # kernel would look like a working kernel path
+            raise RuntimeError(
+                f"attention backend {be.name!r} needs Pallas, which does "
+                "not import on this TPU install")
         be = get_backend("aqua-masked-dense" if aqua_on else "dense-jnp")
     if be.aqua_native and not aqua_on:
         be = get_backend("flash" if _rtf.kernels_preferred() else "dense-jnp")
@@ -1054,18 +1054,27 @@ def build_cache_from_prefill(params: dict, x: jax.Array, cfg: AttentionConfig,
         return cache
 
     # full / window caches: last `slots` tokens, ring-consistent placement.
+    # Those slots form one contiguous run from slot 0 (rotated when a ring
+    # has wrapped), so the cache is built by rolling and padding — no
+    # scatter, which the TPU compiler fails on once fused with the K/V
+    # projections.
     start = max(0, s - slots)
-    ring = cfg.window is not None
-    tok_pos = positions[start:]
-    slot_idx = (tok_pos % slots) if ring else (tok_pos - start)
-    cache = kv.AttnCache(
-        k=cache.k.at[:, :, slot_idx].set(k[:, start:].transpose(0, 2, 1, 3)),
-        v=cache.v.at[:, :, slot_idx].set(v[:, start:].transpose(0, 2, 1, 3)),
-        positions=cache.positions.at[:, slot_idx].set(tok_pos[None]),
+    shift = start % slots if cfg.window is not None else 0
+
+    def place(a, axis, fill=0):            # a holds s - start tokens
+        a = jnp.roll(a, shift, axis=axis) if shift else a
+        pad = [(0, 0)] * a.ndim
+        pad[axis] = (0, slots - (s - start))
+        return jnp.pad(a, pad, constant_values=fill)
+
+    return kv.AttnCache(
+        k=place(k[:, start:].transpose(0, 2, 1, 3), 2),
+        v=place(v[:, start:].transpose(0, 2, 1, 3), 2),
+        positions=jnp.broadcast_to(place(positions[start:], 0, fill=-1),
+                                   (b, slots)),
         count=count,
         acc_score=cache.acc_score,
     )
-    return cache
 
 
 # ---------------------------------------------------------------------------

@@ -167,20 +167,26 @@ def lane_write_tail(cache: AttnCache, lane: jax.Array, k_tail: jax.Array,
     """
     s = cache.num_slots
     t = k_tail.shape[0]
-    ahead = jnp.arange(s) >= start                       # (S,)
-    pos_row = jnp.where(ahead, -1, cache.positions[lane])
+    slot = jnp.arange(s)
+    ahead = slot >= start                                # (S,)
+    inside = ahead & (slot < start + t)                  # chunk's slots
+    src = jnp.clip(slot - start, 0, t - 1)
+    # rows are rebuilt with selects and written back whole: the TPU
+    # compiler fails on the k/v scatter pair this replaces
+    k_row = jnp.where(inside[None, :, None],
+                      k_tail[src].transpose(1, 0, 2).astype(cache.k.dtype),
+                      cache.k[lane])
+    v_row = jnp.where(inside[None, :, None],
+                      v_tail[src].transpose(1, 0, 2).astype(cache.v.dtype),
+                      cache.v[lane])
+    pos_row = jnp.where(inside, positions[src],
+                        jnp.where(ahead, -1, cache.positions[lane]))
     acc_row = jnp.where(ahead[None, :], 0.0, cache.acc_score[lane])
-    idx = start + jnp.arange(t)
-    k = cache.k.at[lane, :, idx].set(k_tail.astype(cache.k.dtype),
-                                     mode="drop")
-    v = cache.v.at[lane, :, idx].set(v_tail.astype(cache.v.dtype),
-                                     mode="drop")
-    pos_row = pos_row.at[idx].set(positions, mode="drop")
-    acc_row = acc_row.at[:, idx].set(0.0, mode="drop")
+    put = lambda a, row: jax.lax.dynamic_update_index_in_dim(a, row, lane, 0)
     return dataclasses.replace(
-        cache, k=k, v=v,
-        positions=cache.positions.at[lane].set(pos_row),
-        acc_score=cache.acc_score.at[lane].set(acc_row),
+        cache, k=put(cache.k, k_row), v=put(cache.v, v_row),
+        positions=put(cache.positions, pos_row),
+        acc_score=put(cache.acc_score, acc_row),
         count=cache.count.at[lane].set(new_count))
 
 

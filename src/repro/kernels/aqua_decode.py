@@ -15,7 +15,10 @@ The value product and online softmax are fused flash-decode style, so the
 
 Grid: (B, H, num_seq_blocks, NB_sel)  — dim-block index j innermost; the
 V block index_map is constant in j, so Pallas keeps the V tile resident
-across the j loop (single fetch per seq block).
+across the j loop (single fetch per seq block). Inside the call the query
+and output carry a singleton row axis — (B, H, NB_sel, 1, bd) and
+(B, H, 1, Dv) — so each block's last two dims equal the array's, as the
+TPU's (8, 128) tiling rule requires.
 
 Mesh-native serving runs this kernel *inside* ``shard_map``
 (``repro.core.attention.shard_mapped_decode_kernel``): B and H are then
@@ -47,13 +50,11 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _kernel(idx_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-            s_ref, m_ref, l_ref, acc_ref, *, scale: float, seq_blk: int,
-            nb_sel: int, nsb: int):
-    b = pl.program_id(0)
-    sb = pl.program_id(2)
-    j = pl.program_id(3)
-
+def _accumulate_scores(sb, j, q_ref, k_ref, s_ref, m_ref, l_ref, acc_ref):
+    """Shared prologue of every decode body: reset the running softmax
+    state at a row's first step, the score tile at each sequence block's
+    first dim-block, then add this selected dim-block's partial scores:
+    (1, bd) @ (bd, S_blk)."""
     @pl.when((sb == 0) & (j == 0))
     def _init():
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
@@ -64,36 +65,54 @@ def _kernel(idx_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
     def _reset_scores():
         s_ref[...] = jnp.zeros_like(s_ref)
 
-    # partial scores for this selected dim-block: (1, bd) @ (bd, S_blk)
-    q_blk = q_ref[0, 0].astype(jnp.float32)          # (1, bd)
+    q_blk = q_ref[0, 0, 0].astype(jnp.float32)       # (1, bd)
     k_blk = k_ref[0, 0, 0].astype(jnp.float32)       # (bd, S_blk)
     s_ref[...] += jax.lax.dot_general(
         q_blk, k_blk, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
 
+
+def _softmax_update(s, valid, v_blk, m_ref, l_ref, acc_ref):
+    """Online-softmax step over one sequence block. ``s`` (1, S_blk)
+    scaled scores, ``valid`` their position mask, ``v_blk`` (S_blk, Dv).
+    The running max and denominator live in (1, 1) VMEM tiles and are
+    read and written whole (``[...]``): Mosaic has no scalar VMEM store."""
+    s = jnp.where(valid, s, NEG_INF)
+    m_prev = m_ref[...]                               # (1, 1)
+    m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)                            # (1, S_blk)
+    corr = jnp.exp(m_prev - m_new)
+    l_ref[...] = l_ref[...] * corr + p.sum(axis=-1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
+        p, v_blk, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    m_ref[...] = m_new
+
+
+def _write_out(o_ref, acc_ref, l_ref):
+    o_ref[...] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+                  )[None, None].astype(o_ref.dtype)
+
+
+def _kernel(idx_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
+            s_ref, m_ref, l_ref, acc_ref, *, scale: float, seq_blk: int,
+            nb_sel: int, nsb: int):
+    b = pl.program_id(0)
+    sb = pl.program_id(2)
+    j = pl.program_id(3)
+    _accumulate_scores(sb, j, q_ref, k_ref, s_ref, m_ref, l_ref, acc_ref)
+
     @pl.when(j == nb_sel - 1)
     def _finalize_block():
-        s = s_ref[...] * scale                        # (1, S_blk)
         pos = sb * seq_blk + jax.lax.broadcasted_iota(jnp.int32, (1, seq_blk),
                                                       1)
-        valid = pos < len_ref[b]
-        s = jnp.where(valid, s, NEG_INF)
-        m_prev = m_ref[0, 0]
-        m_new = jnp.maximum(m_prev, jnp.max(s))
-        p = jnp.exp(s - m_new)                        # (1, S_blk)
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[0, 0] = l_ref[0, 0] * corr + jnp.sum(p)
-        v_blk = v_ref[0, 0].astype(jnp.float32)       # (S_blk, Dv)
-        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-            p, v_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[0, 0] = m_new
+        _softmax_update(s_ref[...] * scale, pos < len_ref[b],
+                        v_ref[0, 0].astype(jnp.float32),
+                        m_ref, l_ref, acc_ref)
 
         @pl.when(sb == nsb - 1)
         def _write():
-            o_ref[...] = (acc_ref[...] /
-                          jnp.maximum(l_ref[0, 0], 1e-30)
-                          ).astype(o_ref.dtype)[None]
+            _write_out(o_ref, acc_ref, l_ref)
 
 
 def _paged_kernel(idx_ref, pt_ref, len_ref, *rest, **kw):
@@ -118,47 +137,20 @@ def _paged_part_kernel(idx_ref, pt_ref, part_ref, len_ref,
     b = pl.program_id(0)
     sb = pl.program_id(2)
     j = pl.program_id(3)
-
-    @pl.when((sb == 0) & (j == 0))
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    @pl.when(j == 0)
-    def _reset_scores():
-        s_ref[...] = jnp.zeros_like(s_ref)
-
-    q_blk = q_ref[0, 0].astype(jnp.float32)          # (1, bd)
-    k_blk = k_ref[0, 0, 0].astype(jnp.float32)       # (bd, S_blk)
-    s_ref[...] += jax.lax.dot_general(
-        q_blk, k_blk, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    _accumulate_scores(sb, j, q_ref, k_ref, s_ref, m_ref, l_ref, acc_ref)
 
     @pl.when(j == nb_sel - 1)
     def _finalize_block():
-        s = s_ref[...] * scale                        # (1, S_blk)
         lp = part_ref[b, sb // bpp]                   # logical page id
         pos = (lp * bpp + sb % bpp) * seq_blk + jax.lax.broadcasted_iota(
             jnp.int32, (1, seq_blk), 1)
-        valid = pos < len_ref[b]
-        s = jnp.where(valid, s, NEG_INF)
-        m_prev = m_ref[0, 0]
-        m_new = jnp.maximum(m_prev, jnp.max(s))
-        p = jnp.exp(s - m_new)                        # (1, S_blk)
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[0, 0] = l_ref[0, 0] * corr + jnp.sum(p)
-        v_blk = v_ref[0, 0].astype(jnp.float32)       # (S_blk, Dv)
-        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-            p, v_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[0, 0] = m_new
+        _softmax_update(s_ref[...] * scale, pos < len_ref[b],
+                        v_ref[0, 0].astype(jnp.float32),
+                        m_ref, l_ref, acc_ref)
 
         @pl.when(sb == nsb - 1)
         def _write():
-            o_ref[...] = (acc_ref[...] /
-                          jnp.maximum(l_ref[0, 0], 1e-30)
-                          ).astype(o_ref.dtype)[None]
+            _write_out(o_ref, acc_ref, l_ref)
 
 
 def _paged_part_quant_kernel(idx_ref, pt_ref, part_ref, len_ref,
@@ -174,49 +166,22 @@ def _paged_part_quant_kernel(idx_ref, pt_ref, part_ref, len_ref,
     h = pl.program_id(1)
     sb = pl.program_id(2)
     j = pl.program_id(3)
-
-    @pl.when((sb == 0) & (j == 0))
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    @pl.when(j == 0)
-    def _reset_scores():
-        s_ref[...] = jnp.zeros_like(s_ref)
-
-    q_blk = q_ref[0, 0].astype(jnp.float32)          # (1, bd)
-    k_blk = k_ref[0, 0, 0].astype(jnp.float32)       # (bd, S_blk) int->f32
-    s_ref[...] += jax.lax.dot_general(
-        q_blk, k_blk, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    _accumulate_scores(sb, j, q_ref, k_ref, s_ref, m_ref, l_ref, acc_ref)
 
     @pl.when(j == nb_sel - 1)
     def _finalize_block():
         lp = part_ref[b, sb // bpp]                   # logical page id
         page = jnp.maximum(pt_ref[b, lp], 0)
         kv = (h // g) * s_stride
-        s = s_ref[...] * (scale * ks_ref[page, kv])   # (1, S_blk)
         pos = (lp * bpp + sb % bpp) * seq_blk + jax.lax.broadcasted_iota(
             jnp.int32, (1, seq_blk), 1)
-        valid = pos < len_ref[b]
-        s = jnp.where(valid, s, NEG_INF)
-        m_prev = m_ref[0, 0]
-        m_new = jnp.maximum(m_prev, jnp.max(s))
-        p = jnp.exp(s - m_new)                        # (1, S_blk)
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[0, 0] = l_ref[0, 0] * corr + jnp.sum(p)
         v_blk = v_ref[0, 0].astype(jnp.float32) * vs_ref[page, kv]
-        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-            p, v_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[0, 0] = m_new
+        _softmax_update(s_ref[...] * (scale * ks_ref[page, kv]),
+                        pos < len_ref[b], v_blk, m_ref, l_ref, acc_ref)
 
         @pl.when(sb == nsb - 1)
         def _write():
-            o_ref[...] = (acc_ref[...] /
-                          jnp.maximum(l_ref[0, 0], 1e-30)
-                          ).astype(o_ref.dtype)[None]
+            _write_out(o_ref, acc_ref, l_ref)
 
 
 def _paged_quant_kernel(idx_ref, pt_ref, len_ref, ks_ref, vs_ref,
@@ -237,48 +202,21 @@ def _paged_quant_kernel(idx_ref, pt_ref, len_ref, ks_ref, vs_ref,
     h = pl.program_id(1)
     sb = pl.program_id(2)
     j = pl.program_id(3)
-
-    @pl.when((sb == 0) & (j == 0))
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    @pl.when(j == 0)
-    def _reset_scores():
-        s_ref[...] = jnp.zeros_like(s_ref)
-
-    q_blk = q_ref[0, 0].astype(jnp.float32)          # (1, bd)
-    k_blk = k_ref[0, 0, 0].astype(jnp.float32)       # (bd, S_blk) int->f32
-    s_ref[...] += jax.lax.dot_general(
-        q_blk, k_blk, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    _accumulate_scores(sb, j, q_ref, k_ref, s_ref, m_ref, l_ref, acc_ref)
 
     @pl.when(j == nb_sel - 1)
     def _finalize_block():
         page = jnp.maximum(pt_ref[b, sb // bpp], 0)
         kv = (h // g) * s_stride
-        s = s_ref[...] * (scale * ks_ref[page, kv])   # (1, S_blk)
         pos = sb * seq_blk + jax.lax.broadcasted_iota(jnp.int32, (1, seq_blk),
                                                       1)
-        valid = pos < len_ref[b]
-        s = jnp.where(valid, s, NEG_INF)
-        m_prev = m_ref[0, 0]
-        m_new = jnp.maximum(m_prev, jnp.max(s))
-        p = jnp.exp(s - m_new)                        # (1, S_blk)
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[0, 0] = l_ref[0, 0] * corr + jnp.sum(p)
         v_blk = v_ref[0, 0].astype(jnp.float32) * vs_ref[page, kv]
-        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-            p, v_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[0, 0] = m_new
+        _softmax_update(s_ref[...] * (scale * ks_ref[page, kv]),
+                        pos < len_ref[b], v_blk, m_ref, l_ref, acc_ref)
 
         @pl.when(sb == nsb - 1)
         def _write():
-            o_ref[...] = (acc_ref[...] /
-                          jnp.maximum(l_ref[0, 0], 1e-30)
-                          ).astype(o_ref.dtype)[None]
+            _write_out(o_ref, acc_ref, l_ref)
 
 
 @functools.partial(jax.jit, static_argnames=("block_dims", "seq_blk",
@@ -352,12 +290,13 @@ def aqua_paged_decode_attention(q_sel: jax.Array, khat_pages: jax.Array,
 
     grid = (b, h, nsb, nb_sel)
     quant = k_scale is not None
+    q_rows = q_sel[:, :, :, None]             # (B, H, NB_sel, 1, bd)
     nsp = (3 if not quant else 5) + (1 if hier else 0)
 
     # trailing scalar-prefetch refs: (idx, pt[, part], len[, ks, vs]) —
     # the maps only dereference idx/pt/part, so *refs covers all arities.
     def q_map(bi, hi, sbi, ji, *refs):
-        return (bi, hi, ji, 0)
+        return (bi, hi, ji, 0, 0)
 
     if hier:
         # sequence-block axis walks participating pages only: grid step
@@ -381,17 +320,17 @@ def aqua_paged_decode_attention(q_sel: jax.Array, khat_pages: jax.Array,
             return (page, hi // g, sbi % bpp, 0)
 
     def o_map(bi, hi, sbi, ji, *refs):
-        return (bi, hi, 0)
+        return (bi, hi, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=nsp,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1, 1, bd), q_map),
+            pl.BlockSpec((1, 1, 1, 1, bd), q_map),
             pl.BlockSpec((1, 1, 1, bd, seq_blk), k_map),
             pl.BlockSpec((1, 1, seq_blk, dv), v_map),
         ],
-        out_specs=pl.BlockSpec((1, 1, dv), o_map),
+        out_specs=pl.BlockSpec((1, 1, 1, dv), o_map),
         scratch_shapes=[
             pltpu.VMEM((1, seq_blk), jnp.float32),   # score accumulator
             pltpu.VMEM((1, 1), jnp.float32),         # running max
@@ -409,31 +348,31 @@ def aqua_paged_decode_attention(q_sel: jax.Array, khat_pages: jax.Array,
         if hier:
             kernel = functools.partial(_paged_part_quant_kernel, **common)
             operands = (block_idx, page_table, part_idx, lengths, *scales,
-                        q_sel, khat_pages, v_pages)
+                        q_rows, khat_pages, v_pages)
         else:
             kernel = functools.partial(_paged_quant_kernel, **common)
             operands = (block_idx, page_table, lengths, *scales,
-                        q_sel, khat_pages, v_pages)
+                        q_rows, khat_pages, v_pages)
     else:
         out_dtype = v_pages.dtype
         if hier:
             kernel = functools.partial(_paged_part_kernel, scale=scale,
                                        seq_blk=seq_blk, nb_sel=nb_sel,
                                        nsb=nsb, bpp=bpp)
-            operands = (block_idx, page_table, part_idx, lengths, q_sel,
+            operands = (block_idx, page_table, part_idx, lengths, q_rows,
                         khat_pages, v_pages)
         else:
             kernel = functools.partial(_paged_kernel, scale=scale,
                                        seq_blk=seq_blk, nb_sel=nb_sel,
                                        nsb=nsb)
-            operands = (block_idx, page_table, lengths, q_sel, khat_pages,
+            operands = (block_idx, page_table, lengths, q_rows, khat_pages,
                         v_pages)
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, dv), out_dtype),
+        out_shape=jax.ShapeDtypeStruct((b, h, 1, dv), out_dtype),
         interpret=interpret,
-    )(*operands)
+    )(*operands)[:, :, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("block_dims", "seq_blk",
@@ -472,7 +411,7 @@ def aqua_decode_attention(q_sel: jax.Array, khat_blocks: jax.Array,
     grid = (b, h, nsb, nb_sel)
 
     def q_map(bi, hi, sbi, ji, idx_ref, len_ref):
-        return (bi, hi, ji, 0)
+        return (bi, hi, ji, 0, 0)
 
     def k_map(bi, hi, sbi, ji, idx_ref, len_ref):
         return (bi, hi // g, idx_ref[bi, hi, ji], 0, sbi)
@@ -481,17 +420,17 @@ def aqua_decode_attention(q_sel: jax.Array, khat_blocks: jax.Array,
         return (bi, hi // g, sbi, 0)
 
     def o_map(bi, hi, sbi, ji, idx_ref, len_ref):
-        return (bi, hi, 0)
+        return (bi, hi, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1, 1, bd), q_map),
+            pl.BlockSpec((1, 1, 1, 1, bd), q_map),
             pl.BlockSpec((1, 1, 1, bd, seq_blk), k_map),
             pl.BlockSpec((1, 1, seq_blk, dv), v_map),
         ],
-        out_specs=pl.BlockSpec((1, 1, dv), o_map),
+        out_specs=pl.BlockSpec((1, 1, 1, dv), o_map),
         scratch_shapes=[
             pltpu.VMEM((1, seq_blk), jnp.float32),   # score accumulator
             pltpu.VMEM((1, 1), jnp.float32),         # running max
@@ -504,6 +443,6 @@ def aqua_decode_attention(q_sel: jax.Array, khat_blocks: jax.Array,
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, dv), v.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, h, 1, dv), v.dtype),
         interpret=interpret,
-    )(block_idx, lengths, q_sel, khat_blocks, v)
+    )(block_idx, lengths, q_sel[:, :, :, None], khat_blocks, v)[:, :, 0]

@@ -27,12 +27,13 @@ from repro.configs import (ALL_ARCHS, ASSIGNED_ARCHS, AquaConfig,  # noqa: E402
                            SHAPES_BY_NAME, get_config)
 from repro.configs.base import ModelConfig, ShapeConfig, TrainConfig  # noqa: E402
 from repro.distributed import sharding as sh  # noqa: E402
-from repro.launch.mesh import (HBM_BW, ICI_BW, PEAK_FLOPS_BF16,  # noqa: E402
-                               make_production_mesh)
+from repro.launch.mesh import chip_peaks, make_production_mesh  # noqa: E402
 from repro.models import build_model  # noqa: E402
 from repro.optim import adamw  # noqa: E402
 
 SDS = jax.ShapeDtypeStruct
+# the production meshes this dry-run sizes are built from v5e chips
+DRYRUN_DEVICE_KIND = "TPU v5 lite"
 
 
 # ---------------------------------------------------------------------------
@@ -344,9 +345,10 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
     # roofline terms: XLA's cost_analysis on the SPMD-partitioned module is
     # PER-PARTITION (verified against a hand-sharded matmul), i.e. already
     # per-chip work — divide only by per-chip capability.
-    t_compute = flops / PEAK_FLOPS_BF16
-    t_memory = bytes_acc / HBM_BW
-    t_coll = coll_total / ICI_BW
+    peaks = chip_peaks(DRYRUN_DEVICE_KIND)
+    t_compute = flops / peaks.bf16_flops
+    t_memory = bytes_acc / peaks.hbm_bw
+    t_coll = coll_total / peaks.ici_link_bw
 
     res = {
         "arch": arch, "shape": shape_name,

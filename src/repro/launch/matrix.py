@@ -71,7 +71,7 @@ def _token_cell(plan) -> str:
 
 def generate_matrix() -> str:
     """The README table (markdown, BEGIN/END markers included)."""
-    mesh = AbstractMesh((("data", 2), ("model", 2)))
+    mesh = AbstractMesh((2, 2), ("data", "model"))
     lines = [
         BEGIN,
         "| backend | contiguous cache @ mesh | paged cache @ mesh "

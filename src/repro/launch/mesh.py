@@ -5,6 +5,7 @@ module never touches jax device state.
 """
 from __future__ import annotations
 
+import dataclasses
 import math
 
 import jax
@@ -65,7 +66,31 @@ def parse_mesh_spec(spec: str):
     return shape, axes
 
 
-# TPU v5e hardware constants used by the roofline analysis (per chip).
-PEAK_FLOPS_BF16 = 197e12        # FLOP/s
-HBM_BW = 819e9                  # bytes/s
-ICI_BW = 50e9                   # bytes/s per link
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    """Published per-chip peaks (roofline denominators)."""
+
+    bf16_flops: float     # FLOP/s
+    hbm_bw: float         # bytes/s
+    ici_link_bw: float    # bytes/s per interconnect link
+
+
+# Keyed by ``jax.Device.device_kind``. Source: Google Cloud documentation,
+# "TPU v5e" (system architecture): 197 TFLOP/s bf16, 819 GB/s of HBM
+# bandwidth, 1,600 Gbit/s of interconnect per chip over four links
+# (50 GB/s each).
+CHIP_PEAKS = {
+    "TPU v5 lite": ChipPeaks(bf16_flops=197e12, hbm_bw=819e9,
+                             ici_link_bw=50e9),
+}
+
+
+def chip_peaks(device_kind: str) -> ChipPeaks:
+    """Peaks of one chip of ``device_kind``; an unknown kind raises rather
+    than borrowing another chip's numbers."""
+    try:
+        return CHIP_PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device_kind "
+                       f"{device_kind!r}; known: {sorted(CHIP_PEAKS)}"
+                       ) from None

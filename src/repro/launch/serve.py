@@ -33,6 +33,7 @@ import numpy as np
 
 import jax
 
+from repro import runtime_flags
 from repro.configs import get_config, reduced
 from repro.configs.base import (AquaConfig, CacheSpec, QuantSpec,
                                 ServingConfig, SparsitySpec)
@@ -44,7 +45,23 @@ from repro.serving import ContinuousBatchingEngine, ServeEngine, \
     poisson_trace
 
 
-def main():
+def init_params(model, seed: int, mesh=None):
+    """Random parameters from ``seed``. Under a mesh every leaf is created
+    in its own shard (``jax.jit`` with the parameter shardings as
+    ``out_shardings``), so no device ever holds the whole model."""
+    key = jax.random.PRNGKey(seed)
+    if mesh is None:
+        return model.init(key)
+    from repro.distributed import sharding as dsh
+    shardings = dsh.make_param_shardings(jax.eval_shape(model.init, key),
+                                         mesh)
+    return jax.jit(model.init, out_shardings=shardings)(key)
+
+
+def main(argv=None) -> dict:
+    """Serve one trace; returns a summary (engine, dispatch plan, the
+    trace, request and token counts, serve wall seconds, streamed tokens
+    per uid)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-0.6b")
     ap.add_argument("--reduced", action="store_true")
@@ -71,6 +88,9 @@ def main():
                     help="block-sparse prefill kernel q-chunk tile (one "
                          "dim-block selection per tile); a chunked-prefill "
                          "budget must be a multiple of it")
+    ap.add_argument("--param-dtype", default=None,
+                    choices=("float32", "bfloat16"),
+                    help="parameter dtype (default: the config's)")
     ap.add_argument("--no-aqua", action="store_true")
     ap.add_argument("--backend", default=None,
                     help="attention backend override (see core.attention)")
@@ -85,7 +105,8 @@ def main():
                     help="max new tokens per request")
     ap.add_argument("--max-seq", type=int, default=128)
     ap.add_argument("--temperature", type=float, default=0.0)
-    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--seed", type=int, default=0,
+                    help="seed of the random weights and of the trace")
     ap.add_argument("--rectangular", action="store_true",
                     help="old fixed-batch ServeEngine drive (comparison)")
     # block-paged KV cache
@@ -152,7 +173,7 @@ def main():
                          "kernels natively on the mesh (guards the CI "
                          "acceptance drive against a dispatch-predicate "
                          "regression silently serving the jnp reference)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     if args.hf_checkpoint is not None:
         from repro.checkpoint.hf import config_from_hf, load_hf_checkpoint
@@ -170,12 +191,22 @@ def main():
             aqua = dataclasses.replace(aqua,
                                        prefill_q_blk=args.prefill_q_blk)
     cfg = dataclasses.replace(cfg, aqua=aqua)
+    if args.param_dtype is not None:
+        cfg = dataclasses.replace(cfg, param_dtype=args.param_dtype)
+
+    from repro.launch.mesh import make_serving_mesh, parse_mesh_spec
+    mesh_spec = parse_mesh_spec(args.mesh)
+    mesh = None
+    if mesh_spec is not None and not args.rectangular:
+        mesh = make_serving_mesh(*mesh_spec)
+        print(f"[serve] mesh {dict(mesh.shape)} over {mesh.size} "
+              f"{mesh.devices.flat[0].platform} devices")
 
     model = build_model(cfg)
     if args.hf_checkpoint is not None:
         params = load_hf_checkpoint(args.hf_checkpoint, cfg)
     else:
-        params = model.init(jax.random.PRNGKey(0))
+        params = init_params(model, args.seed, mesh)
 
     proj = None
     if aqua is not None and args.projections is not None \
@@ -208,16 +239,8 @@ def main():
             print(f"[serve] saved AQUA projections to {args.projections}")
 
     if args.rectangular:
-        _drive_rectangular(cfg, params, proj, args)
-        return
+        return _drive_rectangular(cfg, params, proj, args)
 
-    from repro.launch.mesh import make_serving_mesh, parse_mesh_spec
-    mesh_spec = parse_mesh_spec(args.mesh)
-    mesh = None
-    if mesh_spec is not None:
-        mesh = make_serving_mesh(*mesh_spec)
-        print(f"[serve] mesh {dict(mesh.shape)} over {mesh.size} "
-              f"{mesh.devices.flat[0].platform} devices")
     scfg = ServingConfig(max_lanes=args.lanes, max_seq=args.max_seq,
                          max_new_tokens=args.steps,
                          temperature=args.temperature,
@@ -236,6 +259,9 @@ def main():
     eng = ContinuousBatchingEngine(cfg, params, proj, serving=scfg,
                                    backend=args.backend, mesh=mesh)
     plan = eng.dispatch_plan()
+    print(f"[serve] dispatch plan: backend={plan.backend} "
+          f"layout={plan.cache_layout} quantization={plan.quantization} "
+          f"mesh_native={plan.mesh_native} reasons={list(plan.reasons)}")
     if args.prefill_budget is not None and not plan.chunked_prefill:
         print("[serve] chunked prefill OFF (monolithic admission): "
               f"{'; '.join(plan.chunked_reasons)}")
@@ -431,6 +457,10 @@ def main():
               "on the mesh (no kernel fallback)")
 
     if args.verify:
+        if mesh is not None:
+            # the single-device reference engines below take their own
+            # copy of the weights on one device
+            params = jax.device_put(params, jax.devices()[0])
         # Token-identity reference. At greedy (temperature 0) the trace
         # re-serves on a fresh SINGLE-DEVICE engine — cross-partitioning
         # equality only holds there, since resharding the model axis
@@ -528,6 +558,9 @@ def main():
             print(f"[serve] verify: max inter-token gap "
                   f"{warm_max * 1e3:.1f}ms < monolithic "
                   f"{ref_max * 1e3:.1f}ms (warm re-drives)")
+    return {"engine": eng, "plan": plan, "trace": reqs,
+            "requests": len(reqs), "finished": finished,
+            "tokens": st.tokens_emitted, "serve_s": dt, "outputs": streamed}
 
 
 def _drive_rectangular(cfg, params, proj, args):
@@ -551,7 +584,11 @@ def _drive_rectangular(cfg, params, proj, args):
     print(f"[serve] KV cache bytes @ batch={batch_size}: "
           f"{eng.cache_bytes(batch_size):,}")
     print("[serve] sample:", np.asarray(res.tokens[0])[:16].tolist())
+    return {"engine": eng, "requests": batch_size,
+            "finished": batch_size, "tokens": int(res.tokens.size),
+            "serve_s": dt}
 
 
 if __name__ == "__main__":
+    runtime_flags.configure_compile_cache()
     main()

@@ -23,6 +23,9 @@ back to the masked-dense jnp reference); ``True`` additionally makes the
 mode — useful for kernel-path testing on CPU).
 """
 
+import os
+from pathlib import Path
+
 UNROLL_SCANS = False
 
 # Analysis-only override for chunked-attention block sizes (q_blk, k_blk).
@@ -47,12 +50,11 @@ def attn_blocks(q_blk: int, k_blk: int):
 
 
 def on_tpu() -> bool:
-    """True when the default JAX backend is a TPU."""
+    """True when the default JAX backend is a TPU. A backend that fails to
+    initialize raises here: silently answering False would run every
+    kernel interpreted and every ``auto`` choice on the jnp references."""
     import jax
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # backend init failure -> definitely not a TPU
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def pallas_available() -> bool:
@@ -61,7 +63,7 @@ def pallas_available() -> bool:
         return PALLAS_OVERRIDE
     try:
         from jax.experimental import pallas  # noqa: F401
-    except Exception:
+    except ImportError:
         return False
     return True
 
@@ -85,3 +87,24 @@ def resolve_interpret(interpret=None) -> bool:
     if INTERPRET_OVERRIDE is not None:
         return bool(INTERPRET_OVERRIDE)
     return not on_tpu()
+
+
+# Fixed in-checkout location of JAX's persistent compilation cache (the
+# cache key includes the path, so it must not move between runs).
+COMPILE_CACHE_DIR = Path(__file__).resolve().parents[2] / ".jax_cache"
+
+
+def configure_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is used as is (JAX reads it
+    itself). Otherwise the cache lives at :data:`COMPILE_CACHE_DIR`, a
+    fixed directory of the checkout listed in ``.gitignore``. Entry points
+    (``launch/serve.py``, ``benchmarks/run.py``, ``chip_smoke.py``) call
+    this once before their first compile."""
+    import jax
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(COMPILE_CACHE_DIR)
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path

@@ -27,7 +27,7 @@ from repro.core.dispatch import (CACHE_CONTIGUOUS, CACHE_PAGED,
                                  REASON_REFERENCE_BACKEND, REASON_WINDOW,
                                  DispatchPlan, resolve_dispatch_plan)
 
-MESH = AbstractMesh((("data", 2), ("model", 2)))
+MESH = AbstractMesh((2, 2), ("data", "model"))
 ATT = AttentionConfig(num_heads=4, num_kv_heads=2, head_dim=16,
                       backend="aqua-block-sparse")
 AQUA = AquaConfig(k_ratio=0.5, block_dims=8)

@@ -1,23 +1,14 @@
 """Unit tests for the sharding rules (divisibility fallbacks) using an
 AbstractMesh (no devices needed)."""
 import jax
+import pytest
 from jax.sharding import AbstractMesh, PartitionSpec as P
 
 from repro.distributed import sharding as sh
 
 
-def _abstract_mesh(*axes):
-    """AbstractMesh across JAX signature changes: ((name, size), ...) on
-    0.4.3x, (axis_sizes, axis_names) on newer releases."""
-    try:
-        return AbstractMesh(tuple(axes))
-    except TypeError:
-        return AbstractMesh(tuple(s for _, s in axes),
-                            tuple(n for n, _ in axes))
-
-
-MESH = _abstract_mesh(("data", 16), ("model", 16))
-POD = _abstract_mesh(("pod", 2), ("data", 16), ("model", 16))
+MESH = AbstractMesh((16, 16), ("data", "model"))
+POD = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def _key(name):
@@ -90,7 +81,7 @@ def test_sanitize_batch1_single_kv_serving_cache():
     """Serving cache shapes at the degenerate corners: a single decode
     lane (B=1) and MQA (KV=1) must drop every non-dividing axis
     independently — never crash, never leave a stale axis behind."""
-    serving = _abstract_mesh(("data", 4), ("model", 2))
+    serving = AbstractMesh((4, 2), ("data", "model"))
     # k (L, B, KV, S, D) with B=1 and KV=1: batch/kv axes drop, slots=64
     # absorb data×model (8 | 64)
     spec = sh.decode_state_pspec(_key("k"), (2, 1, 1, 64, 8), serving,
@@ -110,11 +101,11 @@ def test_sanitize_batch1_single_kv_serving_cache():
 
 
 def test_lane_pspec_divisibility():
-    serving = _abstract_mesh(("data", 4), ("model", 2))
+    serving = AbstractMesh((4, 2), ("data", "model"))
     assert sh.lane_pspec(serving, 8) == P(("data",))
     assert sh.lane_pspec(serving, 1) == P(None)    # single lane: replicate
     assert sh.lane_pspec(serving, 6) == P(None)    # 4 does not divide 6
-    modelonly = _abstract_mesh(("model", 2))
+    modelonly = AbstractMesh((2,), ("model",))
     assert sh.lane_pspec(modelonly, 8) == P(None)  # no data axes at all
 
 
@@ -182,3 +173,12 @@ def test_input_specs_cover_all_cells():
             else:
                 assert specs["batch"]["tokens"].shape == (
                     shp.global_batch, shp.seq_len)
+
+
+def test_chip_peaks_keyed_by_device_kind():
+    from repro.launch.mesh import chip_peaks
+
+    v5e = chip_peaks("TPU v5 lite")
+    assert (v5e.bf16_flops, v5e.hbm_bw) == (197e12, 819e9)
+    with pytest.raises(KeyError, match="TPU v4"):
+        chip_peaks("TPU v4")       # unknown kinds raise, never default
