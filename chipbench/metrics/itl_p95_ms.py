@@ -1,0 +1,13 @@
+"""95th percentile of all gaps between consecutive tokens of one request,
+both tokens inside the window, pooled over every request (host clock), in
+cells whose window only decodes: its tail is the decode step's."""
+import numpy as np
+
+from chipbench.drive import itl_gaps
+
+
+def read(run):
+    gaps = itl_gaps(run.window)
+    if not gaps:
+        return None
+    return float(np.percentile(gaps, 95)) * 1e3

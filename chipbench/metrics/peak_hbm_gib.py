@@ -1,0 +1,7 @@
+"""Peak device bytes in use after the window, in GiB (memory_stats)."""
+
+
+def read(run):
+    if run.memory_peak_bytes is None:
+        return None
+    return run.memory_peak_bytes / 2 ** 30
