@@ -157,12 +157,14 @@ def qkv(params: dict, x: jax.Array, cfg: AttentionConfig,
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("aqua.project")
 def project_q(q: jax.Array, proj: Optional[jax.Array]) -> jax.Array:
     if proj is None:
         return q
     return jnp.einsum("bskgd,kde->bskge", q, proj.astype(q.dtype))
 
 
+@jax.named_scope("aqua.project")
 def project_k(k: jax.Array, proj: Optional[jax.Array]) -> jax.Array:
     if proj is None:
         return k
@@ -181,11 +183,13 @@ def _aqua_project(q, k, aqua: Optional[AquaConfig], proj, head_dim: int):
     return qh[..., :kept], kh[..., :kept]
 
 
+@jax.named_scope("aqua.select")
 def _aqua_mask(qh, aqua: AquaConfig, head_dim: int):
     return aqua_lib.magnitude_mask(qh, aqua.topk_dims(head_dim),
                                    block_dims=aqua.block_dims)
 
 
+@jax.named_scope("aqua.select")
 def _chunk_tile_mask(qh, aqua: AquaConfig, q_blk: int,
                      lengths: Optional[jax.Array]):
     """Per-*tile* dim-block mask reproducing the block-sparse kernel's
@@ -1197,8 +1201,9 @@ def decode_attention(params: dict, x_t: jax.Array, cache: kv.AttnCache,
     head_dim = cfg.head_dim
     aqua_on = aqua is not None and aqua.enabled
     if aqua_on:
-        qh = jnp.einsum("bkgd,kde->bkge", q, proj.astype(q.dtype))
-        kh = jnp.einsum("bkd,kde->bke", k_t, proj.astype(k_t.dtype))
+        with jax.named_scope("aqua.project"):
+            qh = jnp.einsum("bkgd,kde->bkge", q, proj.astype(q.dtype))
+            kh = jnp.einsum("bkd,kde->bke", k_t, proj.astype(k_t.dtype))
         kept = aqua.kept_dims(head_dim)
         q, k_t = qh[..., :kept], kh[..., :kept]
 

@@ -120,6 +120,7 @@ def select_slot(cache: AttnCache, *, window: Optional[int],
     return jnp.where(count < s_slots, free, victim)
 
 
+@jax.named_scope("kv.write")
 def insert(cache: AttnCache, slot: jax.Array, k_new: jax.Array,
            v_new: jax.Array,
            write_mask: Optional[jax.Array] = None) -> AttnCache:
@@ -149,6 +150,7 @@ def insert(cache: AttnCache, slot: jax.Array, k_new: jax.Array,
                      acc_score=acc)
 
 
+@jax.named_scope("kv.write")
 def lane_write_tail(cache: AttnCache, lane: jax.Array, k_tail: jax.Array,
                     v_tail: jax.Array, positions: jax.Array,
                     start: jax.Array, new_count: jax.Array) -> AttnCache:
@@ -542,6 +544,7 @@ def paged_select_slot(cache: PagedAttnCache, *, window: Optional[int],
     return slot, evict
 
 
+@jax.named_scope("kv.write")
 def paged_insert(cache: PagedAttnCache, slot: jax.Array, k_new: jax.Array,
                  v_new: jax.Array, write_mask: Optional[jax.Array] = None,
                  evict_page: Optional[jax.Array] = None) -> PagedAttnCache:
@@ -639,6 +642,7 @@ def paged_accumulate_h2o(cache: PagedAttnCache, attn_weights: jax.Array,
     return dataclasses.replace(cache, acc_pool=acc)
 
 
+@jax.named_scope("kv.write")
 def paged_graft(cache: PagedAttnCache, req: AttnCache, lane: jax.Array,
                 num_slots: int) -> PagedAttnCache:
     """Copy logical slots ``[0, num_slots)`` of a B=1 contiguous cache
@@ -719,6 +723,7 @@ def paged_graft(cache: PagedAttnCache, req: AttnCache, lane: jax.Array,
                                count=count, **extra)
 
 
+@jax.named_scope("kv.write")
 def paged_write_tail(cache: PagedAttnCache, lane: jax.Array,
                      k_tail: jax.Array, v_tail: jax.Array,
                      positions: jax.Array, start_page: jax.Array,

@@ -372,6 +372,7 @@ def aqua_paged_decode_attention(q_sel: jax.Array, khat_pages: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, 1, dv), out_dtype),
         interpret=interpret,
+        name="aqua_paged_decode_attention",
     )(*operands)[:, :, 0]
 
 
@@ -445,4 +446,5 @@ def aqua_decode_attention(q_sel: jax.Array, khat_blocks: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, 1, dv), v.dtype),
         interpret=interpret,
+        name="aqua_decode_attention",
     )(block_idx, lengths, q_sel[:, :, :, None], khat_blocks, v)[:, :, 0]

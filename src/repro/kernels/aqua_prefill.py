@@ -321,4 +321,5 @@ def aqua_prefill_attention(q_sel: jax.Array, khat_blocks: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, nqc * q_blk, dv), v.dtype),
         interpret=interpret,
+        name="aqua_prefill_attention",
     )(*prefetch, q_sel, khat_blocks, v)

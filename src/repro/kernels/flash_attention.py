@@ -104,4 +104,5 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         ],
         out_shape=jax.ShapeDtypeStruct((b, h, s, d), v.dtype),
         interpret=interpret,
+        name="flash_attention",
     )(q, k, v)

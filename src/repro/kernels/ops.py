@@ -106,16 +106,18 @@ def aqua_decode(q_hat: jax.Array, khat: jax.Array, v: jax.Array,
     nb = d // block_dims
     k_dims = round_k_dims(d, k_ratio, block_dims)
 
-    block_idx = aqua_lib.topk_block_indices(q_hat, k_dims, block_dims)
-    # gather the selected q blocks (tiny: H × k elements)
-    qb = q_hat.reshape(b, h, nb, block_dims)
-    q_sel = jnp.take_along_axis(qb, block_idx[..., None], axis=2)
+    with jax.named_scope("aqua.select"):
+        block_idx = aqua_lib.topk_block_indices(q_hat, k_dims, block_dims)
+        # gather the selected q blocks (tiny: H × k elements)
+        qb = q_hat.reshape(b, h, nb, block_dims)
+        q_sel = jnp.take_along_axis(qb, block_idx[..., None], axis=2)
 
-    pad = (-s) % seq_blk
-    if pad:
-        khat = jnp.pad(khat, ((0, 0), (0, 0), (0, pad), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, 0), (0, pad), (0, 0)))
-    khat_blocks = to_dim_major_blocks(khat, block_dims)
+    with jax.named_scope("aqua.kv_layout"):
+        pad = (-s) % seq_blk
+        if pad:
+            khat = jnp.pad(khat, ((0, 0), (0, 0), (0, pad), (0, 0)))
+            v = jnp.pad(v, ((0, 0), (0, 0), (0, pad), (0, 0)))
+        khat_blocks = to_dim_major_blocks(khat, block_dims)
     return aqua_decode_attention(q_sel, khat_blocks, v, block_idx, lengths,
                                  block_dims=block_dims, seq_blk=seq_blk,
                                  scale=scale, interpret=interpret)
@@ -161,15 +163,18 @@ def aqua_paged_decode(q_hat: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     nb = d // block_dims
     k_dims = round_k_dims(d, k_ratio, block_dims)
 
-    if block_idx is None:
-        block_idx = aqua_lib.topk_block_indices(q_hat, k_dims, block_dims)
-    qb = q_hat.reshape(b, h, nb, block_dims)
-    q_sel = jnp.take_along_axis(qb, block_idx[..., None], axis=2)
+    with jax.named_scope("aqua.select"):
+        if block_idx is None:
+            block_idx = aqua_lib.topk_block_indices(q_hat, k_dims,
+                                                    block_dims)
+        qb = q_hat.reshape(b, h, nb, block_dims)
+        q_sel = jnp.take_along_axis(qb, block_idx[..., None], axis=2)
 
     seq_blk = min(seq_blk, ps)
     if ps % seq_blk != 0:
         seq_blk = ps
-    khat_pages = to_dim_major_blocks(k_pool, block_dims)  # (P,KV,NB,bd,ps)
+    with jax.named_scope("aqua.kv_layout"):
+        khat_pages = to_dim_major_blocks(k_pool, block_dims)  # (P,KV,NB,bd,ps)
     return aqua_paged_decode_attention(q_sel, khat_pages, v_pool, block_idx,
                                        page_table, lengths,
                                        k_scale, v_scale, part_idx,
@@ -218,14 +223,16 @@ def aqua_prefill(q_hat: jax.Array, khat: jax.Array, v: jax.Array,
     nb = d // block_dims
     k_dims = round_k_dims(d, k_ratio, block_dims)
 
-    block_idx = aqua_lib.chunk_topk_block_indices(q_hat, k_dims, block_dims,
-                                                  q_blk, lengths)
-    # gather selected q dim-blocks per chunk: (B,H,NQC,NB_sel,q_blk,bd)
-    qb = q_hat.reshape(b, h, nqc, q_blk, nb, block_dims
-                       ).transpose(0, 1, 2, 4, 3, 5)
-    q_sel = jnp.take_along_axis(qb, block_idx[..., None, None], axis=3)
+    with jax.named_scope("aqua.select"):
+        block_idx = aqua_lib.chunk_topk_block_indices(
+            q_hat, k_dims, block_dims, q_blk, lengths)
+        # gather selected q dim-blocks per chunk: (B,H,NQC,NB_sel,q_blk,bd)
+        qb = q_hat.reshape(b, h, nqc, q_blk, nb, block_dims
+                           ).transpose(0, 1, 2, 4, 3, 5)
+        q_sel = jnp.take_along_axis(qb, block_idx[..., None, None], axis=3)
 
-    khat_blocks = to_dim_major_blocks(khat, block_dims)
+    with jax.named_scope("aqua.kv_layout"):
+        khat_blocks = to_dim_major_blocks(khat, block_dims)
     out = aqua_prefill_attention(q_sel, khat_blocks, v, block_idx, lengths,
                                  block_dims=block_dims, q_blk=q_blk,
                                  k_blk=k_blk, causal=causal, window=window,
@@ -292,29 +299,32 @@ def aqua_prefill_chunk(q_hat: jax.Array, khat: jax.Array, v: jax.Array,
     k_dims = round_k_dims(d, k_ratio, block_dims)
     kb = k_dims // block_dims
 
-    # chunk-local |q̂| block aggregation — same math as
-    # chunk_topk_block_indices but masked by *global* positions and
-    # carrying the previous chunk's partial leading-tile aggregate
-    mag = jnp.abs(q_hat.astype(jnp.float32))
-    row = jnp.arange(tpad)
-    valid = (row[None, :] < t) & (q_offset + row[None, :] < lengths[:, None])
-    mag = mag * valid[:, None, :, None]
-    bmag = mag.reshape(b, h, nqc, q_blk, nb, block_dims
-                       ).sum(axis=(3, 5))                    # (B,H,NQC,NB)
-    if mag_state is not None:
-        bmag = bmag.at[:, :, 0, :].add(mag_state)
-    if t % q_blk != 0:
-        carry = bmag[:, :, -1, :]
-    else:
-        carry = jnp.zeros((b, h, nb), jnp.float32)
-    _, bidx = jax.lax.top_k(bmag, kb)
-    block_idx = jnp.sort(bidx, axis=-1).astype(jnp.int32)
+    with jax.named_scope("aqua.select"):
+        # chunk-local |q̂| block aggregation — same math as
+        # chunk_topk_block_indices but masked by *global* positions and
+        # carrying the previous chunk's partial leading-tile aggregate
+        mag = jnp.abs(q_hat.astype(jnp.float32))
+        row = jnp.arange(tpad)
+        valid = ((row[None, :] < t)
+                 & (q_offset + row[None, :] < lengths[:, None]))
+        mag = mag * valid[:, None, :, None]
+        bmag = mag.reshape(b, h, nqc, q_blk, nb, block_dims
+                           ).sum(axis=(3, 5))                # (B,H,NQC,NB)
+        if mag_state is not None:
+            bmag = bmag.at[:, :, 0, :].add(mag_state)
+        if t % q_blk != 0:
+            carry = bmag[:, :, -1, :]
+        else:
+            carry = jnp.zeros((b, h, nb), jnp.float32)
+        _, bidx = jax.lax.top_k(bmag, kb)
+        block_idx = jnp.sort(bidx, axis=-1).astype(jnp.int32)
 
-    qb = q_hat.reshape(b, h, nqc, q_blk, nb, block_dims
-                       ).transpose(0, 1, 2, 4, 3, 5)
-    q_sel = jnp.take_along_axis(qb, block_idx[..., None, None], axis=3)
+        qb = q_hat.reshape(b, h, nqc, q_blk, nb, block_dims
+                           ).transpose(0, 1, 2, 4, 3, 5)
+        q_sel = jnp.take_along_axis(qb, block_idx[..., None, None], axis=3)
 
-    khat_blocks = to_dim_major_blocks(khat, block_dims)
+    with jax.named_scope("aqua.kv_layout"):
+        khat_blocks = to_dim_major_blocks(khat, block_dims)
     out = aqua_prefill_attention(q_sel, khat_blocks, v, block_idx, lengths,
                                  block_dims=block_dims, q_blk=q_blk,
                                  k_blk=k_blk, causal=causal, window=window,

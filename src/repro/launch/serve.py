@@ -42,7 +42,7 @@ from repro.data.pipeline import DataConfig, add_frontend_inputs, \
     calibration_batches, make_batch
 from repro.models import build_model
 from repro.serving import ContinuousBatchingEngine, ServeEngine, \
-    poisson_trace
+    poisson_trace, telemetry
 
 
 def init_params(model, seed: int, mesh=None):
@@ -313,6 +313,7 @@ def main(argv=None) -> dict:
                 if k != "tokens"}
 
     t0 = time.time()
+    mark = time.perf_counter_ns()
     finished = 0
     streamed: dict = {}
     for ev in eng.serve(reqs):
@@ -323,6 +324,11 @@ def main(argv=None) -> dict:
                   f"({ev.finish_reason})")
     dt = time.time() - t0
     st = eng.stats
+    spans = telemetry.summary([s for s in telemetry.spans()
+                               if s.start_ns >= mark])
+    print(f"[serve] engine spans (mean): step {spans['step']:.2f}ms "
+          f"(wait {spans['wait']:.2f}ms), admit {spans['admit']:.2f}ms, "
+          f"host gap {spans['host_gap']:.2f}ms")
     print(f"[serve] {finished}/{len(reqs)} requests, "
           f"{st.tokens_emitted} tokens in {dt:.2f}s "
           f"({st.tokens_emitted / dt:.1f} tok/s), "

@@ -171,6 +171,7 @@ class LM:
     # uniform pytree indexing. Families that break this invariant must
     # override these methods.
 
+    @jax.named_scope("kv.write")
     def insert_lane(self, state: DecodeState, req_state: DecodeState,
                     lane: jax.Array) -> DecodeState:
         """Graft a single-request (B=1) decode state into batch row

@@ -59,6 +59,7 @@ from repro.core.dispatch import DispatchPlan, resolve_dispatch_plan
 from repro.core.h2o import h2o_budget
 from repro.models import build_model
 from repro.models.base import DecodeState, PagingSpec
+from repro.serving import telemetry
 from repro.serving.scheduler import (LaneScheduler, PagePool, Request,
                                      RequestOutput, ScheduleStats,
                                      StreamEvent)
@@ -774,6 +775,46 @@ class ContinuousBatchingEngine:
             return None
         return shared, num_new
 
+    def _pop_admission(self, sched: LaneScheduler, now: float):
+        """Pop the next request to admit, with its page plan: (req, plan),
+        or (None, None) when the pool covers no arrived request yet. In
+        paged mode a request only admits while the page pool covers its
+        whole lifetime (workload-to-memory scheduling, not OOM); when the
+        queue head can't fit, up to ``admission_lookahead`` later arrivals
+        may admit first (bounded first-fit, no head-of-line blocking) and
+        the head keeps its exact queue position for the next pass."""
+        skip = 0
+        unbounded = sched.num_active == 0   # nothing will retire
+        while True:
+            cand = sched.pop_admissible(now, skip=skip)
+            if cand is None:
+                break
+            if not self._paged:
+                return cand, None
+            plan = self._plan_pages(cand)
+            if plan is not None:
+                return cand, plan
+            sched.unpop(cand)
+            skip += 1
+            if not unbounded and skip >= self.scfg.admission_lookahead:
+                break
+        if skip > 0 and sched.num_active == 0:
+            raise RuntimeError(
+                f"page pool ({self._num_pages} pages of "
+                f"{self.cache_spec.page_size}) cannot fit any of the "
+                f"{skip} arrived request(s) even with every lane free — "
+                "raise CacheSpec.num_pages")
+        return None, None
+
+    def _admit_len(self, req: Request, page_plan) -> int:
+        """Tokens an admission prefills after bucket padding (a shared
+        prefix excluded; mirrors ``_dispatch_admit``'s batches)."""
+        prefix_len = 0
+        if self._paged and page_plan is not None:
+            prefix_len = len(page_plan[0]) * self.cache_spec.page_size
+        return self._padded_prompt_len(req.prompt_len - prefix_len,
+                                       self.scfg.max_seq - prefix_len)
+
     # -- chunked-prefill planning (host side) --------------------------
     def _should_chunk(self, req: Request, page_plan) -> bool:
         """Chunk this admission? Only when the engine interleaves, the
@@ -782,12 +823,8 @@ class ContinuousBatchingEngine:
         path as a non-chunked engine, kernel-capable under a mesh)."""
         if not self._chunked or req.extra_inputs:
             return False
-        prefix_len = 0
-        if self._paged and page_plan is not None:
-            prefix_len = len(page_plan[0]) * self.cache_spec.page_size
-        padded = self._padded_prompt_len(req.prompt_len - prefix_len,
-                                         self.scfg.max_seq - prefix_len)
-        return padded > self.scfg.prefill_budget_tokens
+        return (self._admit_len(req, page_plan)
+                > self.scfg.prefill_budget_tokens)
 
     def _admit_chunked(self, sched: LaneScheduler, req: Request,
                        page_plan) -> tuple:
@@ -967,52 +1004,37 @@ class ContinuousBatchingEngine:
             return StreamEvent(req.uid, t, 0, d,
                                finish_reason(t, req) if d else "")
 
+        caller_ns = 0      # suspended at yield since the last engine.step
+
+        def hand_over(ev: StreamEvent):
+            nonlocal caller_ns
+            t = time.perf_counter_ns()
+            yield ev
+            caller_ns += time.perf_counter_ns() - t
+
         while sched.has_work:
-            # admissions: fill free lanes with every arrived request. In
-            # paged mode a request only admits while the page pool covers
-            # its whole lifetime (workload-to-memory scheduling, not OOM);
-            # when the queue head can't fit, up to ``admission_lookahead``
-            # later arrivals may admit first (bounded first-fit, no
-            # head-of-line blocking) and the head keeps its exact queue
-            # position for the next pass.
-            while True:
-                req, page_plan, skip = None, None, 0
-                unbounded = sched.num_active == 0   # nothing will retire
-                while True:
-                    cand = sched.pop_admissible(now, skip=skip)
-                    if cand is None:
+            # admissions: fill free lanes with every arrived request the
+            # page pool covers (``_pop_admission``)
+            while sched.can_admit(now):
+                with telemetry.span("engine.admit") as sp:
+                    req, page_plan = self._pop_admission(sched, now)
+                    if req is None:
                         break
-                    plan = None
-                    if self._paged:
-                        plan = self._plan_pages(cand)
-                        if plan is None:
-                            sched.unpop(cand)
-                            skip += 1
-                            if (not unbounded
-                                    and skip >= self.scfg.admission_lookahead):
-                                break
-                            continue
-                    req, page_plan = cand, plan
-                    break
-                if req is None:
-                    if skip > 0 and sched.num_active == 0:
-                        raise RuntimeError(
-                            f"page pool ({self._num_pages} pages of "
-                            f"{self.cache_spec.page_size}) cannot fit any "
-                            f"of the {skip} arrived request(s) even with "
-                            "every lane free — raise CacheSpec.num_pages")
-                    break
-                if self._should_chunk(req, page_plan):
-                    lane, job = self._admit_chunked(sched, req, page_plan)
-                    jobs[lane] = job
-                    stats.chunked_admissions += 1
-                    continue
-                lane = sched.assign(req)
-                tok, done, state, lanes = self._dispatch_admit(
-                    req, lane, state, lanes, rng, use_top_k,
-                    page_plan=page_plan)
-                self.last_state, self.last_lanes = state, lanes
-                yield first_token(req, lane, tok, done)
+                    sp.set(uid=req.uid, prompt=req.prompt_len)
+                    if self._should_chunk(req, page_plan):
+                        lane, job = self._admit_chunked(sched, req,
+                                                        page_plan)
+                        jobs[lane] = job
+                        stats.chunked_admissions += 1
+                        continue
+                    sp.set(padded=self._admit_len(req, page_plan))
+                    lane = sched.assign(req)
+                    tok, done, state, lanes = self._dispatch_admit(
+                        req, lane, state, lanes, rng, use_top_k,
+                        page_plan=page_plan)
+                    self.last_state, self.last_lanes = state, lanes
+                    ev = first_token(req, lane, tok, done)
+                yield from hand_over(ev)
             if sched.num_active == 0:
                 if sched.has_pending:
                     now = max(now, sched.next_arrival)   # idle-jump
@@ -1037,22 +1059,24 @@ class ContinuousBatchingEngine:
                         n = (left // self._chunk_align) * self._chunk_align
                         if n <= 0:
                             break
-                        batch = self._chunk_batch(req, cursor, n)
-                        with self._use_mesh():
-                            if (job["row"] is not None
-                                    and not job["row_set"]):
-                                state = self._chunk_paged(
-                                    self.params, batch, state,
-                                    jnp.int32(lane), job["row"],
-                                    jnp.int32(cursor), self.proj,
-                                    select_q_blk=job["select"])
-                                job["row_set"] = True
-                            else:
-                                state = self._chunk(
-                                    self.params, batch, state,
-                                    jnp.int32(lane), jnp.int32(cursor),
-                                    self.proj,
-                                    select_q_blk=job["select"])
+                        with telemetry.span("engine.prefill_chunk",
+                                            uid=req.uid, tokens=n):
+                            batch = self._chunk_batch(req, cursor, n)
+                            with self._use_mesh():
+                                if (job["row"] is not None
+                                        and not job["row_set"]):
+                                    state = self._chunk_paged(
+                                        self.params, batch, state,
+                                        jnp.int32(lane), job["row"],
+                                        jnp.int32(cursor), self.proj,
+                                        select_q_blk=job["select"])
+                                    job["row_set"] = True
+                                else:
+                                    state = self._chunk(
+                                        self.params, batch, state,
+                                        jnp.int32(lane), jnp.int32(cursor),
+                                        self.proj,
+                                        select_q_blk=job["select"])
                         self.last_state = state
                         sched.advance_prefill(lane, n)
                         stats.prefill_chunks += 1
@@ -1063,25 +1087,28 @@ class ContinuousBatchingEngine:
                     padded = self._chunk_padded_len(cursor, rem)
                     if padded > left:
                         break
-                    batch = self._chunk_batch(req, cursor, rem)
                     jobs.pop(lane)
-                    with self._use_mesh():
-                        tok, done, state, lanes = self._chunk_final(
-                            self.params, batch, state, lanes,
-                            jnp.int32(lane), jnp.int32(cursor), self.proj,
-                            rng, req.max_new_tokens, req.temperature,
-                            req.top_k, req.eos_id, req.uid,
-                            use_top_k=use_top_k,
-                            select_q_blk=job["select"])
-                    self.last_state, self.last_lanes = state, lanes
-                    sched.advance_prefill(lane, rem)
-                    sched.mark_decoding(lane)
-                    stats.prefill_chunks += 1
-                    left -= padded
-                    if job["register"]:
-                        self.page_pool.register_prefix(
-                            req.tokens, job["pages"], req.prompt_len)
-                    yield first_token(req, lane, tok, done)
+                    with telemetry.span("engine.prefill_chunk",
+                                        uid=req.uid, tokens=rem):
+                        batch = self._chunk_batch(req, cursor, rem)
+                        with self._use_mesh():
+                            tok, done, state, lanes = self._chunk_final(
+                                self.params, batch, state, lanes,
+                                jnp.int32(lane), jnp.int32(cursor),
+                                self.proj, rng, req.max_new_tokens,
+                                req.temperature, req.top_k, req.eos_id,
+                                req.uid, use_top_k=use_top_k,
+                                select_q_blk=job["select"])
+                        self.last_state, self.last_lanes = state, lanes
+                        sched.advance_prefill(lane, rem)
+                        sched.mark_decoding(lane)
+                        stats.prefill_chunks += 1
+                        left -= padded
+                        if job["register"]:
+                            self.page_pool.register_prefix(
+                                req.tokens, job["pages"], req.prompt_len)
+                        ev = first_token(req, lane, tok, done)
+                    yield from hand_over(ev)
                     if left <= 0:
                         break
 
@@ -1090,16 +1117,22 @@ class ContinuousBatchingEngine:
             # prefills are in flight — time still advances, so arrivals
             # keep flowing while a long prompt chunks in.
             if sched.num_decoding > 0:
-                with self._use_mesh():
-                    state, lanes, tok, emitted, done = self._step(
-                        self.params, state, lanes, self.proj, rng,
-                        use_top_k=use_top_k)
-                self.last_state, self.last_lanes = state, lanes
-                tok_h = np.asarray(tok)
-                em_h = np.asarray(emitted)
-                done_h = np.asarray(done)
+                with telemetry.span("engine.step", step=stats.decode_steps,
+                                    caller_ms=caller_ns / 1e6) as sp:
+                    with self._use_mesh():
+                        state, lanes, tok, emitted, done = self._step(
+                            self.params, state, lanes, self.proj, rng,
+                            use_top_k=use_top_k)
+                    self.last_state, self.last_lanes = state, lanes
+                    with telemetry.span("engine.step.wait"):
+                        tok_h = np.asarray(tok)
+                        em_h = np.asarray(emitted)
+                        done_h = np.asarray(done)
+                    emitting = int(em_h.sum())
+                    sp.set(lanes=emitting)
+                caller_ns = 0
                 stats.decode_steps += 1
-                stats.occupancy_sum += int(em_h.sum())
+                stats.occupancy_sum += emitting
                 if self._paged:
                     self.page_pool.sample_utilization()
                 now += 1.0
@@ -1116,8 +1149,9 @@ class ContinuousBatchingEngine:
                         self._retire(sched, lane)
                         stats.requests_finished += 1
                         last_emit.pop(req.uid, None)
-                    yield StreamEvent(req.uid, t, idx, d,
-                                      finish_reason(t, req) if d else "")
+                    yield from hand_over(StreamEvent(
+                        req.uid, t, idx, d,
+                        finish_reason(t, req) if d else ""))
             else:
                 now += 1.0
 

@@ -219,6 +219,10 @@ class LaneScheduler:
         return list(self._prefill_order)
 
     # -- admission / retirement ---------------------------------------
+    def can_admit(self, now: float) -> bool:
+        """A lane is free and the queue head has arrived by ``now``."""
+        return bool(self._free) and bool(self._keys) and self._keys[0][0] <= now
+
     def pop_admissible(self, now: float, skip: int = 0) -> Optional[Request]:
         """Pop the (``skip``+1)-th pending request that has arrived, if a
         lane is free. ``skip`` > 0 is the head-of-line lookahead: when the

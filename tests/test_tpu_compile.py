@@ -7,7 +7,14 @@ published attention widths (16 query heads, 8 KV heads, head_dim 128,
 layout and VMEM rules reject here what interpret mode accepts. The
 persistent compilation cache is off around the compiles: entries built
 for a described device cannot be read back without one.
+
+The compiled kernels keep the instruction names a profile's readers
+match (each ``pallas_call`` pins its ``name``), and the AQUA stages
+around them keep their ``jax.named_scope`` in the ops' metadata.
 """
+import dataclasses
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -80,10 +87,52 @@ CASES = {
 }
 
 
+# the kernel instruction each case compiles to, as a profile names it
+KERNEL_NAMES = {"decode_contiguous": "aqua_decode_attention",
+                "prefill_2048": "aqua_prefill_attention",
+                "flash_512": "flash_attention"}
+KERNEL_NAMES.update({k: "aqua_paged_decode_attention"
+                     for k in CASES if k.startswith("decode_paged_")})
+# the AQUA stages each case's wrapper scopes
+SCOPES = {k: {"aqua.select", "aqua.kv_layout"}
+          for k in CASES if not k.startswith("flash")}
+SCOPES["flash_512"] = set()
+
+
 @pytest.mark.parametrize("name", sorted(CASES))
 def test_kernel_compiles_for_v5e(one_chip, name):
     fn, shapes = CASES[name]
     args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
             for s, dt in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    kernels = re.findall(r"^\s*(?:ROOT )?(%[\w.-]+) = [^\n]*"
+                         r'custom_call_target="tpu_custom_call"', text,
+                         flags=re.M)
+    assert [k.rsplit(".", 1)[0] for k in kernels] == [
+        "%" + KERNEL_NAMES[name]]
+    scopes = set(re.findall(r'op_name="[^"]*?\b((?:aqua|kv)\.[a-z_]+)',
+                            text))
+    assert scopes == SCOPES[name]
+
+
+def test_decode_step_module_name():
+    """The engine's decode step lowers to the module the benchmark's
+    ``decode_step_ms`` matches (``jit__step_impl``)."""
+    from repro.configs import reduced
+    from repro.configs.base import CacheSpec, ServingConfig
+    from repro.models import build_model
+    from repro.serving import ContinuousBatchingEngine
+    from repro.serving.engine import _init_lane_state
+    cfg = dataclasses.replace(reduced("qwen3-0.6b"), remat=False, aqua=None)
+    scfg = ServingConfig(max_lanes=2, max_seq=32,
+                         cache=CacheSpec(page_size=8, num_pages=8))
+    eng = ContinuousBatchingEngine(cfg, build_model(cfg).init(
+        jax.random.PRNGKey(0)), None, serving=scfg, backend="dense-jnp")
+    state = eng.model.init_decode_state(2, 32)
+    lowered = eng._step.lower(eng.params, state, _init_lane_state(2),
+                              eng.proj, jax.random.PRNGKey(0),
+                              use_top_k=False)
+    assert re.search(r"^module @jit__step_impl\b", lowered.as_text(),
+                     flags=re.M)
