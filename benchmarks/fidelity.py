@@ -347,10 +347,12 @@ def kernel_bandwidth() -> List[Row]:
     rows.append(("kernel/dense_ref", us_ref, "hbm_bytes_ratio=1.000"))
 
     # paged decode: the same cache content scattered into a *permuted*
-    # page pool — the scalar-prefetched page table restores logical order
-    # inside the kernel's index_map, so the output must match the
-    # contiguous kernel and the HBM score-read ratio is unchanged (pages
-    # only redirect addressing; the pool itself is what shrinks, which
+    # page pool — the scalar-prefetched page list restores logical order
+    # inside the kernel, so the output must match the contiguous kernel.
+    # The ratio row counts the selected k_ratio of K̂, as for the
+    # contiguous kernel; the paged kernel reads whole pages once per KV
+    # head for its G query heads, no more than their selected stripes
+    # where G·NB_sel >= NB_total (the pool itself is what shrinks, which
     # the serving rows report as cache bytes / pool_util)
     from repro.kernels.ops import aqua_paged_decode
     ps = 128
@@ -363,11 +365,11 @@ def kernel_bandwidth() -> List[Row]:
     table = jnp.asarray(perm)[None]                      # (1, npg)
     for kr in (0.5, 0.75):
         us = timeit(lambda: aqua_paged_decode(
-            q, pool_k, pool_v, table, lengths, k_ratio=kr, block_dims=8,
-            seq_blk=ps), iters=3)
+            q, pool_k, pool_v, table, lengths, k_ratio=kr, block_dims=8),
+            iters=3)
         err = float(jnp.max(jnp.abs(
             aqua_paged_decode(q, pool_k, pool_v, table, lengths,
-                              k_ratio=kr, block_dims=8, seq_blk=ps)
+                              k_ratio=kr, block_dims=8)
             - aqua_decode(q, khat, v, lengths, k_ratio=kr))))
         nb, nb_sel = block_counts(d, kr, 8)
         kernel_bytes = (khat.size * 2) * (nb_sel / nb) + v.size * 2
@@ -444,9 +446,9 @@ def quant_fidelity() -> List[Row]:
         for kr in (0.5, 0.75, 1.0):
             out_q = aqua_paged_decode(q, qk_pool, qv_pool, table, lengths,
                                       k_scale=sk_pool, v_scale=sv_pool,
-                                      k_ratio=kr, block_dims=8, seq_blk=ps)
+                                      k_ratio=kr, block_dims=8)
             out_f = aqua_paged_decode(q, deq_k, deq_v, table, lengths,
-                                      k_ratio=kr, block_dims=8, seq_blk=ps)
+                                      k_ratio=kr, block_dims=8)
             err = float(jnp.max(jnp.abs(out_q - out_f)))
             assert err < 1e-4, \
                 f"scale-folded kernel diverged from dequantized pools: " \
@@ -855,9 +857,9 @@ def longcontext_bench() -> List[Row]:
     ident_part = jnp.arange(npg, dtype=jnp.int32)[None]
     out_full = aqua_paged_decode(q, pool_k, pool_v, table, lengths,
                                  part_idx=ident_part, k_ratio=kr,
-                                 block_dims=bd, seq_blk=128)
+                                 block_dims=bd)
     out_plain = aqua_paged_decode(q, pool_k, pool_v, table, lengths,
-                                  k_ratio=kr, block_dims=bd, seq_blk=128)
+                                  k_ratio=kr, block_dims=bd)
     err = float(jnp.max(jnp.abs(out_full - out_plain)))
     assert err == 0.0, \
         f"full participation table is not bit-identical to paged: {err}"
@@ -873,8 +875,7 @@ def longcontext_bench() -> List[Row]:
         acc, table, jnp.full((b,), s, jnp.int32), page_size=128,
         kept_pages=kp, pin_recent_pages=2)
     out_h = aqua_paged_decode(q, pool_k, pool_v, table, lengths,
-                              part_idx=part, k_ratio=kr, block_dims=bd,
-                              seq_blk=128)
+                              part_idx=part, k_ratio=kr, block_dims=bd)
     sel_tok = (part[0][:, None] * 128
                + jnp.arange(128)[None, :]).reshape(-1)
     out_ref = aqua_decode(q, khat[:, :, sel_tok, :], v[:, :, sel_tok, :],

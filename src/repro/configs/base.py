@@ -260,9 +260,9 @@ class QuantSpec:
 
     ``kv_dtype``: pool storage dtype — ``"bf16"`` keeps full-precision
     pools, ``"int8"`` stores per-page symmetric-quantized K̂/V with f32
-    scales living beside the page table (zero-point 0; scales ride the
-    Pallas decode kernel's scalar-prefetch ``index_map`` for
-    dequant-free, scale-folded score accumulation).
+    scales living beside the page table (zero-point 0; the Pallas decode
+    kernel scalar-prefetches them for dequant-free, scale-folded score
+    accumulation).
     ``scale_granularity``: ``"page_head"`` keeps one scale per
     (page, kv-head); ``"page"`` shares one scale across a page's heads
     (half the metadata, coarser clipping).
@@ -319,9 +319,9 @@ class SparsitySpec:
     **Stage 2 (dim sparsity):** AQUA's per-query |q̂| dim-block top-k,
     unchanged, applied only within participating pages.
 
-    The participating-page set rides the Pallas decode kernel's
-    scalar-prefetch ``index_map`` exactly like page ids and quant scales,
-    so non-participating pages cost zero HBM bytes — decode compute and
+    The participating-page set is composed into the Pallas decode
+    kernel's per-lane page list, so non-participating pages cost zero HBM
+    bytes — decode compute and
     bandwidth scale with ``kept_pages``, not context length.
     ``page_keep_ratio=1.0`` disables stage 1 (bit-identical to the plain
     paged kernel: the participation table is the identity map).

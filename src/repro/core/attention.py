@@ -492,13 +492,13 @@ def shard_mapped_paged_decode_kernel(mesh, backend, q, cache, *, cfg, aqua,
     paged branch exactly: the page *pool* (k/v/pos/acc) replicates over the
     data axes — pages are lane-global, any lane may map any physical page,
     so table entries are pool-global ids valid unchanged on every data
-    shard — while its KV-head axis shards over ``model`` (whole dim-blocks
-    and whole pages ride with their head). The page-*table* rows partition
-    with their lanes over the data axes, so each data shard's kernel
-    invocation scalar-prefetches only its own lane group's table rows and
-    dereferences them against its full (KV-sharded) pool slice inside the
-    ``index_map`` — zero collectives inside the mapped region, exactly like
-    the contiguous kernel threads its dim-block indices. q (B, KV, G, Dk);
+    shard — while its KV-head axis shards over ``model`` (whole pages ride
+    with their head). The page-*table* rows partition with their lanes
+    over the data axes, so each data shard's kernel invocation
+    scalar-prefetches only its own lane group's page lists and copies
+    those pages from its full (KV-sharded) pool slice — zero collectives
+    inside the mapped region, exactly like the contiguous kernel threads
+    its dim-block indices. q (B, KV, G, Dk);
     returns (B, KV, G, Dv).
 
     ``part_idx`` (B, KP): hierarchical stage-1 participating-page table.
@@ -840,10 +840,10 @@ def _aqua_block_sparse_decode(q_hat, cache, *, cfg, aqua):
 
 def _aqua_block_sparse_paged_decode(q_hat, cache: kv.PagedAttnCache, *,
                                     cfg, aqua, part_idx=None):
-    """Paged AQUA block-sparse decode: the page table rides the same
-    scalar-prefetch ``index_map`` machinery as the dim-block selection
-    (kernels/aqua_decode.aqua_paged_decode_attention) — pool pages stream
-    HBM→VMEM directly, no gathered lane view is ever materialized.
+    """Paged AQUA block-sparse decode: the kernel reads each lane's pool
+    pages HBM→VMEM through its scalar-prefetched page table
+    (kernels/aqua_decode.aqua_paged_decode_attention); no gathered lane
+    view is ever materialized.
     ``part_idx`` (B, KP) is the hierarchical stage-1 participating-page
     table (``core.selection``), or None to walk every page."""
     from repro.kernels import ops as kops
@@ -855,7 +855,6 @@ def _aqua_block_sparse_paged_decode(q_hat, cache: kv.PagedAttnCache, *,
                                  cache.k_scale, cache.v_scale, part_idx,
                                  k_ratio=aqua.k_ratio,
                                  block_dims=aqua.block_dims,
-                                 seq_blk=aqua.decode_seq_blk,
                                  scale=1.0 / float(cfg.head_dim) ** 0.5)
     return out.reshape(b, kvh, g, -1)
 

@@ -230,8 +230,8 @@ class PagedAttnCache:
     """Per-layer paged attention cache.
 
     k_pool: (P, KV, page_size, Dk) — global key page pool (projected and
-       sliced when AQUA is on; the paged Pallas decode kernel consumes the
-       dim-major transpose view per page, see kernels/aqua_decode.py).
+       sliced when AQUA is on; the paged Pallas decode kernel reads these
+       seq-major pages whole, see kernels/aqua_decode.py).
     v_pool: (P, KV, page_size, Dv)
     pos_pool: (P, page_size) int32 — token position held by each pool
        slot, -1 empty. Stored per *physical* page: positions of a shared
@@ -447,9 +447,9 @@ def paged_lane_view(cache: PagedAttnCache) -> AttnCache:
     contiguous cache would hold, so every reference attention core (and
     the shard_map-wrapped decode core) runs unchanged — this is the
     masked-dense/jnp fallback contract for paged serving. The Pallas
-    decode kernel instead walks the page table in its ``index_map``
-    (kernels/aqua_decode.aqua_paged_decode_attention) and never pays this
-    gather.
+    decode kernel instead reads each lane's pages from the pool through
+    the page table (kernels/aqua_decode.aqua_paged_decode_attention) and
+    never pays this gather.
     """
     b = cache.page_table.shape[0]
     s = cache.num_slots

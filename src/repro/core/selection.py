@@ -17,13 +17,13 @@ per-step :class:`SelectionPlan`:
     (``core.aqua.topk_block_indices``), unchanged, applied only within
     participating pages.
 
-The plan's tables ride the Pallas kernels' ``PrefetchScalarGridSpec``
-scalar-prefetch ``index_map`` machinery exactly like page ids and quant
-scales (``kernels/aqua_decode.py``), so non-participating pages cost
-zero HBM bytes: decode bandwidth scales with ``kept_pages × kept
-dim-blocks``, not context length. ``page_keep_ratio=1.0`` resolves to
-the identity participation table — the kernel walks the same tiles in
-the same order and is bit-identical to the plain paged path.
+The participation table is composed into each lane's page list, which
+the paged decode kernel scalar-prefetches like its quant scales
+(``kernels/aqua_decode.py``), so non-participating pages cost zero HBM
+bytes: decode bandwidth scales with ``kept_pages``, not context length.
+``page_keep_ratio=1.0`` resolves to the identity participation table —
+the kernel walks the same pages in the same order and is bit-identical
+to the plain paged path.
 
 Ranking semantics (shared by the jit path, the numpy ``--verify``
 oracle, and the property tests):

@@ -212,9 +212,8 @@ def decode_state_pspec(path, shape, mesh: Mesh, *,
     kv_ax = model_axis if kv_shardable else None
     # paged-cache leaves: the page *pool* is global across lanes (any lane
     # may map any page), so it never shards over the data axes — KV heads
-    # (and the whole dim-blocks of the dim-major K̂ view riding on the
-    # trailing dim) shard over `model`, page tables ride the lane/batch
-    # axis, positions replicate (tiny).
+    # (whole pages riding with each head) shard over `model`, page
+    # tables ride the lane/batch axis, positions replicate (tiny).
     paged = {"k_pool": 4, "v_pool": 4, "acc_pool": 3, "pos_pool": 2,
              "page_table": 2, "k_scale": 2, "v_scale": 2,
              "k_hot": 4, "v_hot": 4, "hot_ids": 1}.get(name)
@@ -293,10 +292,10 @@ def make_state_shardings(state, mesh: Mesh, *, kv_heads: int, batch: int,
     return jax.tree_util.tree_map_with_path(one, state)
 
 
-# The paged decode kernel tiles each page into whole 8-token sequence
-# sub-blocks (TPU sublane granularity; ops.aqua_paged_decode clamps
-# seq_blk to the page size, so a non-multiple page would leave a ragged
-# tail block the index_map can't address).
+# The paged decode kernel copies whole pages into (page_size, D) VMEM
+# tiles and joins a run of them into one (c·page_size, D) operand; pages
+# of whole 8-token sub-blocks (TPU sublane granularity) keep that join
+# on tile boundaries.
 KERNEL_PAGE_MULTIPLE = 8
 
 

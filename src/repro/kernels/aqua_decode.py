@@ -27,16 +27,17 @@ shard — the engine's kernel-native cache layout never slot-shards or
 dim-splits the K̂ stripes, so the scalar-prefetched block-index tables
 and the ``NB_sel``/``NB_total`` accounting are purely shard-local.
 
-The paged kernel (:func:`aqua_paged_decode_attention`) rides the same
-machinery shard_mapped (``shard_mapped_paged_decode_kernel``): the
-page-table rows it scalar-prefetches are the shard's own lane group's —
-tables partition with their lanes over the data axes — while the page
-pool arrives with its page axis whole per data shard (pages are
-lane-global; ``model`` only partitions the pool's KV-head axis, so whole
-pages and whole dim-blocks ride with each head). Table entries are
-pool-global page ids valid unchanged on every shard, so the ``index_map``
-page dereference needs no translation and no collective — exactly like
-the contiguous kernel's dim-block indices.
+Paged pools take :func:`aqua_paged_decode_attention`, which reads whole
+seq-major pages instead: one grid step per (lane, KV head, run of pages)
+with all G query heads of the KV head together, copying only the pages
+that hold tokens; q arrives with its unselected dim-blocks zeroed. It
+runs shard_mapped (``shard_mapped_paged_decode_kernel``): the page lists
+it scalar-prefetches are the shard's own lane group's — tables partition
+with their lanes over the data axes — while the page pool arrives with
+its page axis whole per data shard (pages are lane-global; ``model``
+only partitions the pool's KV-head axis, so whole pages ride with each
+head). Page ids are pool-global and valid unchanged on every shard, so
+the page dereference needs no translation and no collective.
 """
 from __future__ import annotations
 
@@ -48,13 +49,24 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+# Tokens of whole pages the paged body reads per grid step (a run), and
+# computes per online-softmax update (a chunk).
+RUN_TOKENS = 2048
+CHUNK_TOKENS = 512
 
 
-def _accumulate_scores(sb, j, q_ref, k_ref, s_ref, m_ref, l_ref, acc_ref):
-    """Shared prologue of every decode body: reset the running softmax
-    state at a row's first step, the score tile at each sequence block's
-    first dim-block, then add this selected dim-block's partial scores:
-    (1, bd) @ (bd, S_blk)."""
+def _kernel(idx_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
+            s_ref, m_ref, l_ref, acc_ref, *, scale: float, seq_blk: int,
+            nb_sel: int, nsb: int):
+    """Add one selected dim-block's partial scores, (1, bd) @ (bd, S_blk),
+    to the sequence block's score tile; after the last dim-block, take an
+    online-softmax step over the block. The running max and denominator
+    live in (1, 1) VMEM tiles and are read and written whole (``[...]``):
+    Mosaic has no scalar VMEM store."""
+    b = pl.program_id(0)
+    sb = pl.program_id(2)
+    j = pl.program_id(3)
+
     @pl.when((sb == 0) & (j == 0))
     def _init():
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
@@ -71,309 +83,238 @@ def _accumulate_scores(sb, j, q_ref, k_ref, s_ref, m_ref, l_ref, acc_ref):
         q_blk, k_blk, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
 
-
-def _softmax_update(s, valid, v_blk, m_ref, l_ref, acc_ref):
-    """Online-softmax step over one sequence block. ``s`` (1, S_blk)
-    scaled scores, ``valid`` their position mask, ``v_blk`` (S_blk, Dv).
-    The running max and denominator live in (1, 1) VMEM tiles and are
-    read and written whole (``[...]``): Mosaic has no scalar VMEM store."""
-    s = jnp.where(valid, s, NEG_INF)
-    m_prev = m_ref[...]                               # (1, 1)
-    m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-    p = jnp.exp(s - m_new)                            # (1, S_blk)
-    corr = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * corr + p.sum(axis=-1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-        p, v_blk, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    m_ref[...] = m_new
-
-
-def _write_out(o_ref, acc_ref, l_ref):
-    o_ref[...] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
-                  )[None, None].astype(o_ref.dtype)
-
-
-def _kernel(idx_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-            s_ref, m_ref, l_ref, acc_ref, *, scale: float, seq_blk: int,
-            nb_sel: int, nsb: int):
-    b = pl.program_id(0)
-    sb = pl.program_id(2)
-    j = pl.program_id(3)
-    _accumulate_scores(sb, j, q_ref, k_ref, s_ref, m_ref, l_ref, acc_ref)
-
     @pl.when(j == nb_sel - 1)
     def _finalize_block():
         pos = sb * seq_blk + jax.lax.broadcasted_iota(jnp.int32, (1, seq_blk),
                                                       1)
-        _softmax_update(s_ref[...] * scale, pos < len_ref[b],
-                        v_ref[0, 0].astype(jnp.float32),
-                        m_ref, l_ref, acc_ref)
+        s = jnp.where(pos < len_ref[b], s_ref[...] * scale, NEG_INF)
+        m_prev = m_ref[...]                           # (1, 1)
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)                        # (1, S_blk)
+        corr = jnp.exp(m_prev - m_new)
+        l_ref[...] = l_ref[...] * corr + p.sum(axis=-1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
+            p, v_ref[0, 0].astype(jnp.float32), (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_ref[...] = m_new
 
         @pl.when(sb == nsb - 1)
         def _write():
-            _write_out(o_ref, acc_ref, l_ref)
+            o_ref[...] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+                          )[None, None].astype(o_ref.dtype)
 
 
-def _paged_kernel(idx_ref, pt_ref, len_ref, *rest, **kw):
-    """Paged twin of :func:`_kernel`: the kernel body is identical (the
-    page table is consumed only by the BlockSpec ``index_map``s), so the
-    extra scalar-prefetch ref is simply dropped here."""
-    del pt_ref
-    _kernel(idx_ref, len_ref, *rest, **kw)
+def _grouped_paged_kernel(ids_ref, npg_ref, tail_ref, *refs, scale: float,
+                          pps: int, chunk: int, ne: int, nr: int, sh: int,
+                          cdt):
+    """One grid step per (lane, KV head, run of ``pps`` pages): all G query
+    heads of the KV head attend over the run's whole K̂/V pages together.
+
+    ``ids_ref`` (B·NE,) lists each lane's physical pages in attention order,
+    ``npg_ref`` (B,) how many of them the lane reads and ``tail_ref`` (B,)
+    how many slots of its last one hold tokens; every earlier page is full.
+    Only those pages are copied HBM→VMEM, one async copy per page into a
+    double buffer: step t copies the next grid step's run into slot
+    (t+1) % 2 before it computes its own run from slot t % 2, so the grid
+    runs in order ("arbitrary"). A run past the lane's length copies and
+    computes nothing. Full pages are computed ``chunk`` at a time. q
+    arrives with its unselected dim-blocks zeroed, so a whole page's
+    (G, D)·(D, ps) product is the selected-dims score. int8
+    pools fold their per-page scales (flattened (P·SH,), scalar-prefetched)
+    into the score scale and p."""
+    quant = sh > 0
+    if quant:
+        ks_ref, vs_ref, *refs = refs
+    (q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem,
+     m_ref, l_ref, acc_ref) = refs
+    b, h, r = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    nkv = pl.num_programs(1)
+    step = (b * nkv + h) * nr + r
+    slot = step % 2
+
+    def run_len(bi, ri):
+        return jnp.clip(npg_ref[bi] - ri * pps, 0, pps)
+
+    def copies(bi, hi, ri, sl, i):
+        page = ids_ref[bi * ne + ri * pps + i]
+        return (pltpu.make_async_copy(k_hbm.at[page, hi], kbuf.at[sl, i],
+                                      sem.at[0, sl]),
+                pltpu.make_async_copy(v_hbm.at[page, hi], vbuf.at[sl, i],
+                                      sem.at[1, sl]))
+
+    def start_run(bi, hi, ri, sl):
+        def body(i, carry):
+            for c in copies(bi, hi, ri, sl, i):
+                c.start()
+            return carry
+        jax.lax.fori_loop(0, run_len(bi, ri), body, 0)
+
+    @pl.when(step == 0)
+    def _first():
+        start_run(b, h, r, slot)
+
+    nh = jnp.where(r == nr - 1, h + 1, h)
+    nbi = jnp.where(nh == nkv, b + 1, b)
+
+    @pl.when(nbi < pl.num_programs(0))
+    def _prefetch_next():
+        start_run(nbi, jnp.where(nh == nkv, 0, nh),
+                  jnp.where(r == nr - 1, 0, r + 1), 1 - slot)
+
+    @pl.when(r == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    q = q_ref[...].astype(cdt)                        # (G, D)
+    _, _, ps, d = kbuf.shape
+    dv = vbuf.shape[-1]
+
+    def per_token(ref, pages):
+        """(1, len(pages)·ps) row of each page's scale from ``ref``."""
+        return jnp.concatenate([jnp.full((1, ps), ref[pg], jnp.float32)
+                                for pg in pages], axis=1)
+
+    def attend(i0, c, tail=None):
+        """Online-softmax update over the run's pages i0 .. i0+c-1 at once:
+        (G, D)·(D, c·ps) scores, then (G, c·ps)·(c·ps, Dv) values."""
+        pages = [copies(b, h, r, slot, i0 + j) for j in range(c)]
+        for kc, _ in pages:
+            kc.wait()
+        k = kbuf[slot, pl.ds(i0, c)].reshape(c * ps, d).astype(cdt)
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        if quant:
+            sidx = [ids_ref[b * ne + r * pps + i0 + j] * sh
+                    + (h if sh > 1 else 0) for j in range(c)]
+            s = s * per_token(ks_ref, sidx)
+        if tail is not None:
+            s = jnp.where(jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1)
+                          < tail, s, NEG_INF)
+        m_prev = m_ref[...]                           # (G, 1)
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        l_ref[...] = l_ref[...] * corr + p.sum(axis=-1, keepdims=True)
+        m_ref[...] = m_new
+        for _, vc in pages:
+            vc.wait()
+        v = vbuf[slot, pl.ds(i0, c)].reshape(c * ps, dv).astype(jnp.float32)
+        if tail is not None:
+            v = jnp.where(jax.lax.broadcasted_iota(jnp.int32, (ps, 1), 0)
+                          < tail, v, 0.0)
+        if quant:
+            p = p * per_token(vs_ref, sidx)
+        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
+            p, v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    # whole chunks of full pages, then single full pages; the run holding
+    # the lane's last page masks that page's tail slots
+    left = npg_ref[b] - r * pps
+    last = (left > 0) & (left <= pps)
+    n = run_len(b, r)
+    n_full = jnp.where(last, n - 1, n)
+    n_chunks = n_full // chunk
+
+    def chunk_body(j, carry):
+        attend(j * chunk, chunk)
+        return carry
+
+    def page_body(i, carry):
+        attend(i, 1)
+        return carry
+
+    jax.lax.fori_loop(0, n_chunks, chunk_body, 0)
+    jax.lax.fori_loop(n_chunks * chunk, n_full, page_body, 0)
+
+    @pl.when(last)
+    def _tail():
+        attend(n - 1, 1, tail_ref[b])
+
+    @pl.when(r == nr - 1)
+    def _write():
+        o_ref[...] = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
 
 
-def _paged_part_kernel(idx_ref, pt_ref, part_ref, len_ref,
-                       q_ref, k_ref, v_ref, o_ref,
-                       s_ref, m_ref, l_ref, acc_ref, *, scale: float,
-                       seq_blk: int, nb_sel: int, nsb: int, bpp: int):
-    """Hierarchical (two-stage) twin of :func:`_paged_kernel`.
+@functools.partial(jax.jit, static_argnames=("pages_per_step", "scale",
+                                             "interpret"))
+def aqua_paged_decode_attention(q: jax.Array, k_pool: jax.Array,
+                                v_pool: jax.Array, page_ids: jax.Array,
+                                num_pages: jax.Array, tails: jax.Array,
+                                k_scale=None, v_scale=None, *,
+                                pages_per_step: int, scale: float,
+                                interpret=None) -> jax.Array:
+    """AQUA decode attention over a paged K̂/V pool, all G query heads of a
+    KV head per grid step (:func:`_grouped_paged_kernel`).
 
-    The grid's sequence-block axis runs over *participating* pages only
-    (``nsb = KP * bpp``); ``part_ref`` (B, KP) maps each grid step to its
-    logical page so the position validity test stays token-exact. Pages
-    the stage-1 ranking dropped are never touched — their HBM bytes are
-    simply not streamed (the BlockSpec ``index_map`` never emits them)."""
-    b = pl.program_id(0)
-    sb = pl.program_id(2)
-    j = pl.program_id(3)
-    _accumulate_scores(sb, j, q_ref, k_ref, s_ref, m_ref, l_ref, acc_ref)
+    q:          (B, H, D) projected query with each head's unselected
+                dim-blocks zeroed (the AQUA selection, applied as a mask)
+    k_pool:     (P, KV, ps, D) projected key pool, seq-major per page
+    v_pool:     (P, KV, ps, Dv)
+    page_ids:   (B, NE) int32 — physical page of each attended entry, in
+                order; entries at or past ``num_pages[b]`` are never read
+    num_pages:  (B,) int32 — entries each lane attends
+    tails:      (B,) int32 — token slots in use in the lane's last entry
+                (``ps`` when it is full); every earlier entry is full
+    k_scale, v_scale: (P, SH) f32 per-page scales of int8 pools (SH ∈
+                {KV, 1}), or None
+    returns:    (B, H, Dv) float32
 
-    @pl.when(j == nb_sel - 1)
-    def _finalize_block():
-        lp = part_ref[b, sb // bpp]                   # logical page id
-        pos = (lp * bpp + sb % bpp) * seq_blk + jax.lax.broadcasted_iota(
-            jnp.int32, (1, seq_blk), 1)
-        _softmax_update(s_ref[...] * scale, pos < len_ref[b],
-                        v_ref[0, 0].astype(jnp.float32),
-                        m_ref, l_ref, acc_ref)
-
-        @pl.when(sb == nsb - 1)
-        def _write():
-            _write_out(o_ref, acc_ref, l_ref)
-
-
-def _paged_part_quant_kernel(idx_ref, pt_ref, part_ref, len_ref,
-                             ks_ref, vs_ref, q_ref, k_ref, v_ref, o_ref,
-                             s_ref, m_ref, l_ref, acc_ref, *, scale: float,
-                             seq_blk: int, nb_sel: int, nsb: int, bpp: int,
-                             g: int, s_stride: int):
-    """Hierarchical int8 variant: :func:`_paged_part_kernel`'s logical-page
-    remap composed with :func:`_paged_quant_kernel`'s scale folding — the
-    per-page scales are looked up through the participating page's table
-    entry, positions through its logical index."""
-    b = pl.program_id(0)
-    h = pl.program_id(1)
-    sb = pl.program_id(2)
-    j = pl.program_id(3)
-    _accumulate_scores(sb, j, q_ref, k_ref, s_ref, m_ref, l_ref, acc_ref)
-
-    @pl.when(j == nb_sel - 1)
-    def _finalize_block():
-        lp = part_ref[b, sb // bpp]                   # logical page id
-        page = jnp.maximum(pt_ref[b, lp], 0)
-        kv = (h // g) * s_stride
-        pos = (lp * bpp + sb % bpp) * seq_blk + jax.lax.broadcasted_iota(
-            jnp.int32, (1, seq_blk), 1)
-        v_blk = v_ref[0, 0].astype(jnp.float32) * vs_ref[page, kv]
-        _softmax_update(s_ref[...] * (scale * ks_ref[page, kv]),
-                        pos < len_ref[b], v_blk, m_ref, l_ref, acc_ref)
-
-        @pl.when(sb == nsb - 1)
-        def _write():
-            _write_out(o_ref, acc_ref, l_ref)
-
-
-def _paged_quant_kernel(idx_ref, pt_ref, len_ref, ks_ref, vs_ref,
-                        q_ref, k_ref, v_ref, o_ref,
-                        s_ref, m_ref, l_ref, acc_ref, *, scale: float,
-                        seq_blk: int, nb_sel: int, nsb: int, bpp: int,
-                        g: int, s_stride: int):
-    """int8 paged variant: dequant-free score accumulation.
-
-    The int8 K̂ tiles feed the same dot_general (upcast in-register); the
-    per-page key scale is *folded into the softmax scale* at finalize —
-    every sequence block lives inside exactly one physical page, so one
-    scalar multiply replaces a per-element dequant of the K tile. V tiles
-    dequantize once per (b, h, sb) with their page's scalar. The scales
-    ride scalar prefetch (SMEM) like the page table; ``s_stride`` is 1
-    for per-(page, head) scales and 0 for one-scale-per-page."""
-    b = pl.program_id(0)
-    h = pl.program_id(1)
-    sb = pl.program_id(2)
-    j = pl.program_id(3)
-    _accumulate_scores(sb, j, q_ref, k_ref, s_ref, m_ref, l_ref, acc_ref)
-
-    @pl.when(j == nb_sel - 1)
-    def _finalize_block():
-        page = jnp.maximum(pt_ref[b, sb // bpp], 0)
-        kv = (h // g) * s_stride
-        pos = sb * seq_blk + jax.lax.broadcasted_iota(jnp.int32, (1, seq_blk),
-                                                      1)
-        v_blk = v_ref[0, 0].astype(jnp.float32) * vs_ref[page, kv]
-        _softmax_update(s_ref[...] * (scale * ks_ref[page, kv]),
-                        pos < len_ref[b], v_blk, m_ref, l_ref, acc_ref)
-
-        @pl.when(sb == nsb - 1)
-        def _write():
-            _write_out(o_ref, acc_ref, l_ref)
-
-
-@functools.partial(jax.jit, static_argnames=("block_dims", "seq_blk",
-                                             "scale", "interpret"))
-def aqua_paged_decode_attention(q_sel: jax.Array, khat_pages: jax.Array,
-                                v_pages: jax.Array, block_idx: jax.Array,
-                                page_table: jax.Array, lengths: jax.Array,
-                                k_scale=None, v_scale=None, part_idx=None,
-                                *, block_dims: int = 8, seq_blk: int = 128,
-                                scale=None, interpret=None) -> jax.Array:
-    """Block-sparse AQUA decode attention over a *paged* K/V pool.
-
-    q_sel:       (B, H, NB_sel, bd)  — query, pre-gathered selected blocks
-    khat_pages:  (P, KV, NB_total, bd, ps) — dim-major projected key pool
-                 (page-major: each physical page holds a ``ps``-token
-                 dim-major stripe)
-    v_pages:     (P, KV, ps, Dv)
-    block_idx:   (B, H, NB_sel) int32 — selected dim-block ids (sorted)
-    page_table:  (B, NP_lane) int32 — physical page of each logical page,
-                 -1 unmapped (clamped; masked off via ``lengths``)
-    lengths:     (B,) int32 — valid cache length per row. Full-cache
-                 policy only: logical slot == token position.
-    k_scale, v_scale: (P, SH) f32 per-page scales for int8 pools (SH ∈
-                 {KV, 1}); both None for full-precision pools.
-    part_idx:    (B, KP) int32 — stage-1 *participating* logical page
-                 indices per lane, sorted ascending
-                 (``core.selection.participating_pages``), or None to
-                 attend every page. Entries must be valid logical indices
-                 in [0, NP_lane); pages past the lane's length contribute
-                 nothing (position masking). When given, the grid's
-                 sequence-block extent shrinks from NP_lane to KP — the
-                 dropped pages' K̂/V tiles are never streamed from HBM.
-    returns out: (B, H, Dv)
-
-    The page table is the second scalar-prefetch operand: the K and V
-    ``index_map``s dereference it to locate the physical page of each
-    sequence block — the same scalar-prefetch indirection the dim-block
-    selection already uses, composed on the sequence axis. HBM traffic is
-    unchanged vs the contiguous kernel (pages only redirect addressing);
-    the pool itself is what shrinks (repro.core.kvcache.PagedAttnCache).
-
-    Quantized pools compose on the same machinery: the per-page scales
-    are scalar-prefetch operands 4/5, the int8 K̂ tile feeds the MXU
-    upcast in-register, and the key scale folds into the softmax scale at
-    finalize — no dequantized K/V page ever materializes
-    (:func:`_paged_quant_kernel`). HBM score-read traffic drops a further
-    4× vs bf16 pools (1 byte/elem), compounding with the ``k_ratio``
-    dim-sparsity term.
-
-    Shard-local contract: under a serving mesh this runs inside
-    ``shard_map`` with B the shard's lane-group extent and ``page_table``
-    that group's rows, while ``khat_pages``/``v_pages`` keep their page
-    axis whole (P is pool-global; only KV is shard-local, over ``model``).
-    The entries of ``page_table`` are pool-global page ids, so the
-    ``index_map`` dereference above is valid verbatim on every shard.
+    Grid (B, KV, ceil(NE / pages_per_step)). Each K̂/V page is read once
+    per KV head. Scores and the value product accumulate in f32; the score
+    product runs in the operands' common dtype (int8 pages upcast to q's;
+    f32 holds a bf16 × bf16 product exactly). Under a serving mesh B and
+    KV are shard-local and ``page_ids`` holds pool-global ids.
     """
     from repro import runtime_flags as _rtf
-    b, h, nb_sel, bd = q_sel.shape
-    _, kvh, nb_total, bd2, ps = khat_pages.shape
-    assert bd == bd2 == block_dims
-    npl = page_table.shape[1]
-    dv = v_pages.shape[-1]
+    b, h, d = q.shape
+    _, kvh, ps, _ = k_pool.shape
+    dv = v_pool.shape[-1]
     g = h // kvh
-    assert ps % seq_blk == 0, (ps, seq_blk)
-    bpp = ps // seq_blk                       # sequence blocks per page
-    hier = part_idx is not None
-    nsb = (part_idx.shape[1] if hier else npl) * bpp
-    if scale is None:
-        scale = 1.0 / ((nb_total * bd) ** 0.5)
-    interpret = _rtf.resolve_interpret(interpret)
-
-    grid = (b, h, nsb, nb_sel)
+    ne = page_ids.shape[1]
+    pps = pages_per_step
+    nr = pl.cdiv(ne, pps)
     quant = k_scale is not None
-    q_rows = q_sel[:, :, :, None]             # (B, H, NB_sel, 1, bd)
-    nsp = (3 if not quant else 5) + (1 if hier else 0)
+    cdt = q.dtype if quant else jnp.promote_types(q.dtype, k_pool.dtype)
+    sh = k_scale.shape[1] if quant else 0
+    scalars = [page_ids.reshape(-1), num_pages, tails]
+    if quant:
+        scalars += [k_scale.astype(jnp.float32).reshape(-1),
+                    v_scale.astype(jnp.float32).reshape(-1)]
 
-    # trailing scalar-prefetch refs: (idx, pt[, part], len[, ks, vs]) —
-    # the maps only dereference idx/pt/part, so *refs covers all arities.
-    def q_map(bi, hi, sbi, ji, *refs):
-        return (bi, hi, ji, 0, 0)
-
-    if hier:
-        # sequence-block axis walks participating pages only: grid step
-        # sbi -> logical page part[bi, sbi // bpp] -> physical page.
-        def k_map(bi, hi, sbi, ji, *refs):
-            lp = refs[2][bi, sbi // bpp]
-            page = jnp.maximum(refs[1][bi, lp], 0)
-            return (page, hi // g, refs[0][bi, hi, ji], 0, sbi % bpp)
-
-        def v_map(bi, hi, sbi, ji, *refs):
-            lp = refs[2][bi, sbi // bpp]
-            page = jnp.maximum(refs[1][bi, lp], 0)
-            return (page, hi // g, sbi % bpp, 0)
-    else:
-        def k_map(bi, hi, sbi, ji, *refs):
-            page = jnp.maximum(refs[1][bi, sbi // bpp], 0)
-            return (page, hi // g, refs[0][bi, hi, ji], 0, sbi % bpp)
-
-        def v_map(bi, hi, sbi, ji, *refs):
-            page = jnp.maximum(refs[1][bi, sbi // bpp], 0)
-            return (page, hi // g, sbi % bpp, 0)
-
-    def o_map(bi, hi, sbi, ji, *refs):
+    def head_map(bi, hi, ri, *refs):
         return (bi, hi, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=nsp,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, 1, 1, bd), q_map),
-            pl.BlockSpec((1, 1, 1, bd, seq_blk), k_map),
-            pl.BlockSpec((1, 1, seq_blk, dv), v_map),
-        ],
-        out_specs=pl.BlockSpec((1, 1, 1, dv), o_map),
+        num_scalar_prefetch=len(scalars),
+        grid=(b, kvh, nr),
+        in_specs=[pl.BlockSpec((None, g, None, d), head_map),
+                  pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((None, g, None, dv), head_map),
         scratch_shapes=[
-            pltpu.VMEM((1, seq_blk), jnp.float32),   # score accumulator
-            pltpu.VMEM((1, 1), jnp.float32),         # running max
-            pltpu.VMEM((1, 1), jnp.float32),         # running denom
-            pltpu.VMEM((1, dv), jnp.float32),        # output accumulator
+            pltpu.VMEM((2, pps, ps, d), k_pool.dtype),
+            pltpu.VMEM((2, pps, ps, dv), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),          # (K/V, slot)
+            pltpu.VMEM((g, 1), jnp.float32),          # running max
+            pltpu.VMEM((g, 1), jnp.float32),          # running denom
+            pltpu.VMEM((g, dv), jnp.float32),         # output accumulator
         ],
     )
-    if quant:
-        common = dict(scale=scale, seq_blk=seq_blk, nb_sel=nb_sel, nsb=nsb,
-                      bpp=bpp, g=g,
-                      s_stride=1 if k_scale.shape[1] > 1 else 0)
-        # int8 pools can't carry the output dtype; accumulate/emit f32.
-        out_dtype = jnp.float32
-        scales = (k_scale.astype(jnp.float32), v_scale.astype(jnp.float32))
-        if hier:
-            kernel = functools.partial(_paged_part_quant_kernel, **common)
-            operands = (block_idx, page_table, part_idx, lengths, *scales,
-                        q_rows, khat_pages, v_pages)
-        else:
-            kernel = functools.partial(_paged_quant_kernel, **common)
-            operands = (block_idx, page_table, lengths, *scales,
-                        q_rows, khat_pages, v_pages)
-    else:
-        out_dtype = v_pages.dtype
-        if hier:
-            kernel = functools.partial(_paged_part_kernel, scale=scale,
-                                       seq_blk=seq_blk, nb_sel=nb_sel,
-                                       nsb=nsb, bpp=bpp)
-            operands = (block_idx, page_table, part_idx, lengths, q_rows,
-                        khat_pages, v_pages)
-        else:
-            kernel = functools.partial(_paged_kernel, scale=scale,
-                                       seq_blk=seq_blk, nb_sel=nb_sel,
-                                       nsb=nsb)
-            operands = (block_idx, page_table, lengths, q_rows, khat_pages,
-                        v_pages)
+    kernel = functools.partial(_grouped_paged_kernel, scale=scale, pps=pps,
+                               chunk=max(1, min(pps, CHUNK_TOKENS // ps)),
+                               ne=ne, nr=nr, sh=sh, cdt=cdt)
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, 1, dv), out_dtype),
-        interpret=interpret,
+        out_shape=jax.ShapeDtypeStruct((b, h, 1, dv), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",) * 3),
+        interpret=_rtf.resolve_interpret(interpret),
         name="aqua_paged_decode_attention",
-    )(*operands)[:, :, 0]
+    )(*scalars, q.astype(jnp.float32)[:, :, None], k_pool, v_pool)[:, :, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("block_dims", "seq_blk",

@@ -12,10 +12,9 @@ model layer and viewed dim-major ``(B, KV, NB, bd, S)`` by the kernels,
 where ``NB = D // bd`` dim-blocks of ``bd`` sublanes each span the full
 lane-dim sequence stripe. Magnitude selection picks whole dim-blocks, so
 the kernels stream only the selected ``NB_sel`` stripes HBM→VMEM. The
-block-paged cache keeps the same layout *per page* — ``(P, KV, NB, bd,
-page_size)`` — and :func:`aqua_paged_decode` threads the per-lane page
-table through the kernel's scalar-prefetch ``index_map`` so the physical
-page of each sequence block resolves inside the kernel.
+block-paged pool is seq-major per page, ``(P, KV, page_size, D)``, and
+:func:`aqua_paged_decode` reads those pages whole, all G query heads of a
+KV head at once, with q masked by the selection: no relayout per step.
 
 Shard-local contract (mesh-native serving): these wrappers are also the
 bodies run inside ``shard_map`` by ``repro.core.attention`` — every
@@ -31,10 +30,10 @@ shard-local (they partition with their lanes over the data axes), while
 the page *pool* arrives with its page axis whole on every data shard —
 pages are lane-global, any lane may map any physical page, so the
 shard-local table entries are pool-global page ids that dereference
-unchanged inside the kernel's ``index_map``. Only the pool's KV-head
-axis is shard-local (partitioned over ``model``, whole dim-blocks and
-whole pages riding with their head); no collective is ever needed
-between the table lookup and the page DMA.
+unchanged inside the kernel. Only the pool's KV-head axis is
+shard-local (partitioned over ``model``, whole pages riding with their
+head); no collective is ever needed between the table lookup and the
+page DMA.
 """
 from __future__ import annotations
 
@@ -47,8 +46,8 @@ import jax.numpy as jnp
 
 from repro.core import aqua as aqua_lib
 from repro.core.aqua import ceil_to as _ceil_to
-from repro.kernels.aqua_decode import (aqua_decode_attention,
-                                       aqua_paged_decode_attention)
+from repro.kernels.aqua_decode import (
+    RUN_TOKENS, aqua_decode_attention, aqua_paged_decode_attention)
 from repro.kernels.aqua_prefill import aqua_prefill_attention
 from repro.kernels.flash_attention import flash_attention  # noqa: F401
 
@@ -123,9 +122,34 @@ def aqua_decode(q_hat: jax.Array, khat: jax.Array, v: jax.Array,
                                  scale=scale, interpret=interpret)
 
 
+def pages_per_step(page_size: int, lane_pages: int) -> int:
+    """Pages of one lane a grid step of the whole-page decode body reads:
+    up to ``RUN_TOKENS`` tokens, at most the lane's pages."""
+    return max(1, min(lane_pages, RUN_TOKENS // page_size))
+
+
+def attended_pages(page_table: jax.Array, lengths: jax.Array,
+                   part_idx: Optional[jax.Array], page_size: int) -> tuple:
+    """The whole-page body's per-lane page list: (ids (B, NE) physical
+    pages in attention order, n (B,) entries that hold tokens, tails (B,)
+    tokens in the n-th entry). Without ``part_idx`` the list is the page
+    table; with it, entry i is logical page ``part_idx[b, i]`` (sorted
+    ascending, so the entries holding tokens come first)."""
+    used = (lengths + page_size - 1) // page_size
+    if part_idx is None:
+        ids, n, last = page_table, used, used - 1
+    else:
+        ids = jnp.take_along_axis(page_table, part_idx, axis=1)
+        n = jnp.sum(part_idx < used[:, None], axis=1)
+        last = jnp.take_along_axis(part_idx, jnp.maximum(n - 1, 0)[:, None],
+                                   axis=1)[:, 0]
+    tails = jnp.clip(lengths - last * page_size, 0, page_size)
+    return (jnp.maximum(ids, 0).astype(jnp.int32), n.astype(jnp.int32),
+            tails.astype(jnp.int32))
+
+
 @functools.partial(jax.jit, static_argnames=("k_ratio", "block_dims",
-                                             "seq_blk", "scale",
-                                             "interpret"))
+                                             "scale", "interpret"))
 def aqua_paged_decode(q_hat: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                       page_table: jax.Array, lengths: jax.Array,
                       k_scale: Optional[jax.Array] = None,
@@ -133,7 +157,7 @@ def aqua_paged_decode(q_hat: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                       part_idx: Optional[jax.Array] = None,
                       block_idx: Optional[jax.Array] = None, *,
                       k_ratio: float = 0.75, block_dims: int = 8,
-                      seq_blk: int = 128, scale: Optional[float] = None,
+                      scale: Optional[float] = None,
                       interpret: Optional[bool] = None) -> jax.Array:
     """End-to-end AQUA decode attention over a *paged* KV pool.
 
@@ -146,17 +170,17 @@ def aqua_paged_decode(q_hat: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     scale (dequant-free score accumulation).
     part_idx: (B, KP) int32 stage-1 participating logical pages per lane
     (``core.selection.participating_pages``), or None for all pages —
-    hierarchical AQUA's token-sparsity table, also scalar-prefetched;
-    the kernel walks only those KP pages. block_idx: precomputed (B, H,
-    NB_sel) stage-2 dim-block selection (a ``SelectionPlan``'s), or None
-    to select here from ``q_hat`` magnitudes.
+    hierarchical AQUA's token-sparsity table, composed into each lane's
+    page list (:func:`attended_pages`); the kernel walks only those KP
+    pages. block_idx: precomputed (B, H, NB_sel) stage-2 dim-block
+    selection (a ``SelectionPlan``'s), or None to select here from
+    ``q_hat`` magnitudes.
 
-    Same magnitude selection as :func:`aqua_decode`; the physical page of
-    each sequence block is resolved inside the kernel's scalar-prefetch
-    ``index_map`` from the page table — no gathered contiguous view is
-    ever materialized. ``seq_blk`` is clamped to the page size (a sequence
-    block never spans pages); non-divisible remainders fall back to one
-    block per page.
+    Same magnitude selection as :func:`aqua_decode`, applied as a mask:
+    each head's unselected dim-blocks of q̂ are zeroed, and the kernel
+    reads the pool's own seq-major pages, only those that hold tokens
+    (:func:`~repro.kernels.aqua_decode.aqua_paged_decode_attention`). No
+    gathered contiguous view is ever materialized.
     """
     b, h, d = q_hat.shape
     ps = k_pool.shape[2]
@@ -167,20 +191,16 @@ def aqua_paged_decode(q_hat: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
         if block_idx is None:
             block_idx = aqua_lib.topk_block_indices(q_hat, k_dims,
                                                     block_dims)
-        qb = q_hat.reshape(b, h, nb, block_dims)
-        q_sel = jnp.take_along_axis(qb, block_idx[..., None], axis=2)
+        keep = jnp.zeros((b, h, nb), q_hat.dtype)
+        keep = jnp.put_along_axis(keep, block_idx, 1, axis=-1, inplace=False)
+        q_in = q_hat * jnp.repeat(keep, block_dims, axis=-1)
 
-    seq_blk = min(seq_blk, ps)
-    if ps % seq_blk != 0:
-        seq_blk = ps
-    with jax.named_scope("aqua.kv_layout"):
-        khat_pages = to_dim_major_blocks(k_pool, block_dims)  # (P,KV,NB,bd,ps)
-    return aqua_paged_decode_attention(q_sel, khat_pages, v_pool, block_idx,
-                                       page_table, lengths,
-                                       k_scale, v_scale, part_idx,
-                                       block_dims=block_dims,
-                                       seq_blk=seq_blk, scale=scale,
-                                       interpret=interpret)
+    ids, n, tails = attended_pages(page_table, lengths, part_idx, ps)
+    out = aqua_paged_decode_attention(
+        q_in, k_pool, v_pool, ids, n, tails, k_scale, v_scale,
+        pages_per_step=pages_per_step(ps, ids.shape[1]),
+        scale=d ** -0.5 if scale is None else scale, interpret=interpret)
+    return out if k_scale is not None else out.astype(v_pool.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("k_ratio", "block_dims",

@@ -120,8 +120,7 @@ def test_hier_kernel_retrieves_needle_when_mass_ranks_it_in():
         pin_recent_pages=2)
     assert 3 in np.asarray(part)[0]
     out = aqua_paged_decode(q, pool_k, pool_v, table, lengths,
-                            part_idx=part, k_ratio=1.0, block_dims=8,
-                            seq_blk=PS)
+                            part_idx=part, k_ratio=1.0, block_dims=8)
     # softmax is dominated by the needle -> output pulled to its value
     assert float(jnp.max(jnp.abs(out - 5.0))) < 0.5, out
 
@@ -137,8 +136,7 @@ def test_hier_kernel_misses_needle_when_page_dropped():
         pin_recent_pages=2)
     assert 3 not in np.asarray(part)[0]                 # sink + tail only
     out = aqua_paged_decode(q, pool_k, pool_v, table, lengths,
-                            part_idx=part, k_ratio=1.0, block_dims=8,
-                            seq_blk=PS)
+                            part_idx=part, k_ratio=1.0, block_dims=8)
     # the needle's value never streams: output stays near the background
     assert float(jnp.max(jnp.abs(out - 5.0))) > 2.0, out
 
@@ -152,9 +150,9 @@ def test_full_participation_bit_identical_to_paged_kernel():
     for kr in (0.5, 1.0):
         out_h = aqua_paged_decode(q, pool_k, pool_v, table, lengths,
                                   part_idx=ident, k_ratio=kr,
-                                  block_dims=8, seq_blk=PS)
+                                  block_dims=8)
         out_p = aqua_paged_decode(q, pool_k, pool_v, table, lengths,
-                                  k_ratio=kr, block_dims=8, seq_blk=PS)
+                                  k_ratio=kr, block_dims=8)
         np.testing.assert_array_equal(np.asarray(out_h), np.asarray(out_p))
 
 

@@ -23,9 +23,9 @@ from jax.sharding import SingleDeviceSharding
 from repro.kernels import ops as kops
 
 B, H, KV, D = 8, 16, 8, 128          # lanes and qwen3-0.6b attention widths
-S, P, PS, KP = 4096, 256, 128, 8     # cache slots, pool pages, page size,
+S, P, PS, KP = 4096, 320, 128, 8     # cache slots, pool pages, page size,
                                      # hierarchical kept pages
-DECODE = dict(k_ratio=0.75, block_dims=8, seq_blk=128, interpret=False)
+DECODE = dict(k_ratio=0.75, block_dims=8, interpret=False)
 
 
 @pytest.fixture(scope="module")
@@ -45,20 +45,21 @@ def one_chip():
     cc.reset_cache()
 
 
-def _paged(k_dtype, scale_heads=None, part=False):
+def _paged(k_dtype, scale_heads=None, part=False, b=B, lane_pages=S // PS,
+           heads=H):
     def fn(q, k, v, table, lengths, *extra):
         extra = list(extra)
         part_idx = extra.pop() if part else None
         ks, vs = extra if scale_heads else (None, None)
         return kops.aqua_paged_decode(q, k, v, table, lengths, ks, vs,
                                       part_idx, **DECODE)
-    shapes = [((B, H, D), jnp.bfloat16), ((P, KV, PS, D), k_dtype),
-              ((P, KV, PS, D), k_dtype), ((B, S // PS), jnp.int32),
-              ((B,), jnp.int32)]
+    shapes = [((b, heads, D), jnp.bfloat16), ((P, KV, PS, D), k_dtype),
+              ((P, KV, PS, D), k_dtype), ((b, lane_pages), jnp.int32),
+              ((b,), jnp.int32)]
     if scale_heads:
         shapes += [((P, scale_heads), jnp.float32)] * 2
     if part:
-        shapes.append(((B, KP), jnp.int32))
+        shapes.append(((b, KP), jnp.int32))
     return fn, shapes
 
 
@@ -73,6 +74,12 @@ CASES = {
     "decode_paged_hierarchical": _paged(jnp.bfloat16, part=True),
     "decode_paged_hierarchical_int8": _paged(jnp.int8, scale_heads=KV,
                                              part=True),
+    # the benchmark cells' lanes and pages per lane (long-decode-8k,
+    # short-chat), each over a 320-page pool
+    "decode_paged_bf16_4x80": _paged(jnp.bfloat16, b=4, lane_pages=80),
+    "decode_paged_bf16_32x10": _paged(jnp.bfloat16, b=32, lane_pages=10),
+    # one query head per KV head (G = 1), as qwen1.5-4b: whole pages too
+    "decode_paged_bf16_g1": _paged(jnp.bfloat16, heads=KV),
     "prefill_2048": (
         lambda q, k, v, lengths: kops.aqua_prefill(
             q, k, v, lengths, k_ratio=0.75, block_dims=8, q_blk=128,
@@ -93,8 +100,10 @@ KERNEL_NAMES = {"decode_contiguous": "aqua_decode_attention",
                 "flash_512": "flash_attention"}
 KERNEL_NAMES.update({k: "aqua_paged_decode_attention"
                      for k in CASES if k.startswith("decode_paged_")})
-# the AQUA stages each case's wrapper scopes
-SCOPES = {k: {"aqua.select", "aqua.kv_layout"}
+# the AQUA stages each case's wrapper scopes: the paged cases read whole
+# seq-major pages, with no relayout
+SCOPES = {k: {"aqua.select"} if k.startswith("decode_paged_")
+          else {"aqua.select", "aqua.kv_layout"}
           for k in CASES if not k.startswith("flash")}
 SCOPES["flash_512"] = set()
 
