@@ -5,9 +5,11 @@ the served requests, drawn from the seed and always holding the one served
 the most tokens, is run through the float32 reference over its prompt and
 its served tokens. For every served token the gap is the reference's best
 logit minus the reference's logit of that token, at the position whose
-logits chose it (greedy decoding). Two numbers summarise the gaps of the
-sample: the widest gap and the mean gap. The cell's file names the numbers
-it compares and their limits.
+logits chose it (greedy decoding). Three numbers summarise the gaps of the
+sample: the widest gap, the mean gap, and the mean gap in doubt: the mean
+over the positions where the reference's first choice was in doubt (its
+best and second-best logits lie within ``DOUBT`` of each other) or was not
+served. The cell's file names the numbers it compares and their limits.
 
 The control (``control=True``, never in a benchmark run) is the same
 reference computed with float8 operands: at the same positions of the same
@@ -26,13 +28,32 @@ import numpy as np
 from chipbench import reference
 from chipbench.traffic import seed_rng
 
-# the numbers a cell can compare, each a summary of the sample's gaps
-NUMBERS = {"widest_gap": np.max, "mean_gap": np.mean}
+# a reference margin (best minus second-best logit) under which a token is
+# in doubt: about the logit error of the float8 control, whose widest gap
+# reads 0.49-0.97 in qwen1.5-4b-10l.long-decode-8k
+DOUBT = 0.5
+
+
+def mean_gap_in_doubt(gaps: np.ndarray, margins: np.ndarray) -> float:
+    """Mean gap over the positions in doubt or missed (every other gap is
+    0). Unlike the mean gap it does not shrink with the share of positions
+    where one token stands out (as in a greedy loop of random weights),
+    where neither a sound path nor a lower precision changes the token."""
+    counted = (margins < DOUBT) | (gaps > 0)
+    return float(np.sum(gaps) / max(int(np.sum(counted)), 1))
+
+
+# the numbers a cell can compare, each a summary of the sample's gaps and
+# the reference's margins at the same positions
+NUMBERS = {"widest_gap": lambda g, m: float(np.max(g)),
+           "mean_gap": lambda g, m: float(np.mean(g)),
+           "mean_gap_in_doubt": mean_gap_in_doubt}
 
 
 @dataclasses.dataclass
 class Readings:
     gaps: Dict[int, np.ndarray]              # uid -> gap of each served token
+    margins: Dict[int, np.ndarray]           # uid -> reference margin there
     control_gaps: Optional[Dict[int, np.ndarray]] = None
     seconds: float = 0.0
     distinct: int = 0                        # distinct served tokens compared
@@ -49,7 +70,8 @@ class Readings:
         g = self.control_gaps if control else self.gaps
         if not g:
             return float("nan")
-        return float(NUMBERS[name](np.concatenate(list(g.values()))))
+        return NUMBERS[name](np.concatenate(list(g.values())),
+                             np.concatenate([self.margins[u] for u in g]))
 
 
 def pick(served: Dict[int, list], finished: Dict[int, float], rule: dict,
@@ -78,13 +100,13 @@ def padded_len(n: int, conf: dict, q_chunk: int) -> int:
     return -(-n // m) * m
 
 
-def compare(conf: dict, params, proj, prompts: Dict[int, np.ndarray],
+def compare(arch, conf: dict, params, proj, prompts: Dict[int, np.ndarray],
             served: Dict[int, list], sample: List[int], max_seq: int,
             control: bool = False, q_chunk: int = 256) -> Readings:
     t0 = time.perf_counter()
     table = reference.unembed_table(conf, params)
     length = padded_len(max_seq, conf, q_chunk)
-    gaps, ctrl, seen = {}, {}, set()
+    gaps, margins, ctrl, seen = {}, {}, {}, set()
     for uid in sample:
         prompt = np.asarray(prompts[uid], np.int32)
         out = np.asarray(served[uid], np.int32)
@@ -94,14 +116,16 @@ def compare(conf: dict, params, proj, prompts: Dict[int, np.ndarray],
         seq[p:p + n - 1] = out[:-1]
         pos = np.arange(p - 1, p - 1 + n)
         seen.update(out.tolist())
-        h = reference.hidden(conf, params, proj, seq, p, q_chunk=q_chunk)
-        gaps[uid] = reference.served_gaps(table, h, pos, out)
+        h = reference.hidden(arch, conf, params, proj, seq, p,
+                             q_chunk=q_chunk)
+        gaps[uid], margins[uid] = reference.served_gaps(table, h, pos, out)
         if control:
-            hc = reference.hidden(conf, params, proj, seq, p, quant="fp8",
-                                  q_chunk=q_chunk)
+            hc = reference.hidden(arch, conf, params, proj, seq, p,
+                                  quant="fp8", q_chunk=q_chunk)
             choice = reference.control_choice(table, hc, pos, "fp8")
-            ctrl[uid] = reference.served_gaps(table, h, pos, choice)
-    return Readings(gaps=gaps, control_gaps=ctrl if control else None,
+            ctrl[uid], _ = reference.served_gaps(table, h, pos, choice)
+    return Readings(gaps=gaps, margins=margins,
+                    control_gaps=ctrl if control else None,
                     seconds=time.perf_counter() - t0, distinct=len(seen))
 
 

@@ -33,7 +33,7 @@ from chipbench.check import NUMBERS  # noqa: E402
 
 def summary(r, control: bool) -> dict:
     """The numbers a limit can be set from, for the served path and (with
-    ``control``) the control: the widest gap and the mean gap, with the
+    ``control``) the control: every number of ``check.NUMBERS``, with the
     share of compared tokens whose gap is above 0 and how many distinct
     tokens were served."""
     out = {"tokens": r.tokens, "distinct": r.distinct,

@@ -1,23 +1,26 @@
-"""The plain reference: weights from the seed, AQUA calibration, and a
-float32 forward pass with the same AQUA semantics as the served path.
+"""The plain reference: AQUA calibration, and a float32 forward pass with
+the same AQUA semantics as the served path.
 
-Nothing here imports the program. The weights are made here, from the seed,
-in the layout the program's dense model takes (its ``init`` tree), and the
-same arrays are handed to the program and to this reference. The AQUA
-projections are calibrated here too (paper section 6.1: per layer and KV
-head, the eigenvectors of the Gram matrix of the post-RoPE queries of the
-group and the shared key, in descending order of variance), from the
-reference's own activations on ``data/calibration.txt``.
+Nothing here imports the program. The model itself (its weights from the
+seed, in the layout the program's model takes, and its float32 forward
+pass over every layer, in whatever order and pattern its layers come) is
+the configuration's architecture module, ``chipbench/arch/<architecture>.py``;
+the same weights are handed to the program and to this reference. What
+every architecture shares is here: the products with their float8 control,
+RMSNorm, RoPE, the AQUA selection and attention core, the unembedding and
+the gaps, and the calibration of the AQUA projections (paper section 6.1:
+per layer and KV head, the eigenvectors of the Gram matrix of the post-RoPE
+queries of the group and the shared key, in descending order of variance),
+from the reference's own activations on ``data/calibration.txt``.
 
-Semantics of the forward pass, per layer: RMSNorm, q/k/v projections (with
-bias where the configuration has it), per-head q/k RMSNorm where it has
-that, half-split RoPE, q-hat = q P and k-hat = k P per KV head, then
-attention on q-hat masked to the selected dim-blocks against the full k-hat,
-scaled by 1/sqrt(head_dim), causal, softmax, times V, output projection;
-then a gated SiLU MLP. The selection keeps the ``k_dims`` / ``block_dims``
-blocks with the largest summed |q-hat|: per 128-query tile of the prompt,
-summed over the tile's prompt rows (the prefill kernel's selection), and
-per query for every token decoded after the prompt.
+AQUA attention: q-hat = q P and k-hat = k P per KV head, then attention on
+q-hat masked to the selected dim-blocks against the full k-hat, scaled by
+1/sqrt(head_dim), over the keys each query may see (causal unless the
+module says otherwise), softmax, times V. The selection keeps the
+``k_dims`` / ``block_dims`` blocks with the largest summed |q-hat|: per
+128-query tile of the prompt, summed over the tile's prompt rows (the
+prefill kernel's selection), and per query for every token decoded after
+the prompt.
 
 Every matrix product runs at ``Precision.HIGHEST``. ``quant="fp8"`` rounds
 both operands of every product to float8 e4m3 (weights per tensor,
@@ -37,56 +40,11 @@ from chipbench.yardstick import round_k_dims
 HI = jax.lax.Precision.HIGHEST
 F8_MAX = 448.0
 NEG_INF = -1e30
-# random weights: the std of q/k/v biases
-BIAS_STD = 0.5
 
 
 # ---------------------------------------------------------------------------
 # Weights
 # ---------------------------------------------------------------------------
-
-
-def init_params(conf: dict, key: jax.Array) -> dict:
-    """Random weights in the program's dense-model layout, in the
-    configuration's parameter dtype. Weights are N(0, 1) over the square
-    root of their fan-in; biases N(0, ``BIAS_STD``); norm scales 1."""
-    d, f, v = conf["hidden_size"], conf["intermediate_size"], conf["vocab_size"]
-    n = conf["num_hidden_layers"]
-    h, kvh, hd = (conf["num_attention_heads"], conf["num_key_value_heads"],
-                  conf["head_dim"])
-    g = h // kvh
-    serve = conf["serve"]
-    dt = jnp.dtype(serve["param_dtype"])
-    keys = iter(jax.random.split(key, 16))
-
-    def normal(shape, fan_in=None, std=None):
-        std = fan_in ** -0.5 if std is None else std
-        return (jax.random.normal(next(keys), shape, jnp.float32)
-                * std).astype(dt)
-
-    ones = lambda *s: jnp.ones(s, dt)
-    attn = {"wq": normal((n, d, kvh, g, hd), d),
-            "wk": normal((n, d, kvh, hd), d),
-            "wv": normal((n, d, kvh, hd), d),
-            "wo": normal((n, kvh, g, hd, d), h * hd)}
-    if serve["qkv_bias"]:
-        attn["bq"] = normal((n, kvh, g, hd), std=BIAS_STD)
-        attn["bk"] = normal((n, kvh, hd), std=BIAS_STD)
-        attn["bv"] = normal((n, kvh, hd), std=BIAS_STD)
-    if serve["qk_norm"]:
-        attn["q_norm"] = ones(n, hd)
-        attn["k_norm"] = ones(n, hd)
-    params = {
-        "embed": {"table": normal((v, d), d)},
-        "layers": {"ln1": ones(n, d), "ln2": ones(n, d), "attn": attn,
-                   "ffn": {"w1": normal((n, d, f), d),
-                           "w2": normal((n, f, d), f),
-                           "w3": normal((n, d, f), d)}},
-        "ln_f": ones(d),
-    }
-    if not conf["tie_word_embeddings"]:
-        params["unembed"] = {"table": normal((v, d), d)}
-    return params
 
 
 def weights_key(seed: int) -> jax.Array:
@@ -150,25 +108,19 @@ def _select_mask(qh, prompt_len, k_dims, bd, q_blk):
     return jnp.repeat(bmask, bd, axis=-1)
 
 
-def _layer(conf, quant, x, layer, proj, positions, prompt_len, q_chunk):
-    a = conf["serve"]
+def causal(qpos, kpos):
+    """Keys each query may see: (Q, K) bool, every key at or before it."""
+    return kpos[None, :] <= qpos[:, None]
+
+
+def aqua_attention(conf, quant, q, k, v, proj, positions, prompt_len,
+                   q_chunk, sees=causal):
+    """AQUA attention of one sequence: q (T, KV, G, D), k and v (T, KV, D)
+    after RoPE, ``proj`` (KV, D, D); returns (T, KV, G, D). ``sees(qpos,
+    kpos)`` gives the keys each query may see."""
     aq = conf["aqua"]
-    eps = float(conf["rms_norm_eps"])
-    t = x.shape[0]
+    t = q.shape[0]
     hd = conf["head_dim"]
-    p = jax.tree.map(lambda w: w.astype(jnp.float32), layer)
-    at = p["attn"]
-    h = _rms(x, p["ln1"], eps)
-    q = _dot("tm,mkgd->tkgd", h, at["wq"], quant, -1)
-    k = _dot("tm,mkd->tkd", h, at["wk"], quant, -1)
-    v = _dot("tm,mkd->tkd", h, at["wv"], quant, -1)
-    if a["qkv_bias"]:
-        q, k, v = q + at["bq"], k + at["bk"], v + at["bv"]
-    if a["qk_norm"]:
-        q = _rms(q, at["q_norm"], eps)
-        k = _rms(k, at["k_norm"], eps)
-    theta = float(conf["rope_theta"])
-    q, k = _rope(q, positions, theta), _rope(k, positions, theta)
     qh = _dot("tkgd,kde->tkge", q, proj, quant, (2, 3))
     kh = _dot("tkd,kde->tke", k, proj, quant, (1, 2))
     k_dims = round_k_dims(hd, aq["k_ratio"], aq["block_dims"])
@@ -180,18 +132,11 @@ def _layer(conf, quant, x, layer, proj, positions, prompt_len, q_chunk):
         qc = jax.lax.dynamic_slice_in_dim(qq, i * q_chunk, q_chunk, 0)
         s = _dot("ckgd,tkd->ckgt", qc, kh, quant, (1, 2, 3), (0, 2)) * scale
         qpos = i * q_chunk + jnp.arange(q_chunk)
-        s = jnp.where((positions[None, :] <= qpos[:, None])[:, None, None],
-                      s, NEG_INF)
+        s = jnp.where(sees(qpos, positions)[:, None, None], s, NEG_INF)
         w = jax.nn.softmax(s, axis=-1)
         return _dot("ckgt,tkd->ckgd", w, v, quant, (1, 2, 3), (0, 2))
     o = jax.lax.map(chunk, jnp.arange(t // q_chunk))
-    o = o.reshape(t, *o.shape[2:])
-    x = x + _dot("tkgd,kgdm->tm", o, at["wo"], quant, (1, 2, 3))
-    h = _rms(x, p["ln2"], eps)
-    ffn = p["ffn"]
-    up = jax.nn.silu(_dot("tm,mf->tf", h, ffn["w1"], quant, -1)) \
-        * _dot("tm,mf->tf", h, ffn["w3"], quant, -1)
-    return x + _dot("tf,fm->tm", up, ffn["w2"], quant, -1)
+    return o.reshape(t, *o.shape[2:])
 
 
 def unembed_table(conf: dict, params: dict):
@@ -201,30 +146,23 @@ def unembed_table(conf: dict, params: dict):
 
 @functools.partial(jax.jit, static_argnames=("conf_key", "quant", "q_chunk"))
 def _hidden(params, proj, tokens, prompt_len, *, conf_key, quant, q_chunk):
-    conf = _CONFS[conf_key]
-    t = tokens.shape[0]
-    positions = jnp.arange(t, dtype=jnp.int32)
-    x = params["embed"]["table"][tokens].astype(jnp.float32)
-
-    def body(xc, lp):
-        layer, pr = lp
-        return _layer(conf, quant, xc, layer, pr, positions, prompt_len,
-                      q_chunk), None
-    x, _ = jax.lax.scan(body, x, (params["layers"], proj))
-    return _rms(x, params["ln_f"].astype(jnp.float32),
-                float(conf["rms_norm_eps"]))
+    arch, conf = _CONFS[conf_key]
+    return arch.hidden(conf, quant, params, proj, tokens, prompt_len,
+                       q_chunk)
 
 
-# jit needs hashable statics: configurations are registered by name
+# jit needs hashable statics: (architecture module, configuration) pairs
+# are registered by the configuration's name
 _CONFS: dict = {}
 
 
-def hidden(conf: dict, params, proj, tokens: np.ndarray, prompt_len: int,
-           quant: Optional[str] = None, q_chunk: int = 256):
-    """Final normed hidden states (T, d) in float32 for one sequence;
-    ``tokens`` is padded to a multiple of ``q_chunk`` and of the prefill
-    tile. Positions past the real sequence are ignored by the caller."""
-    _CONFS[conf["name"]] = conf
+def hidden(arch, conf: dict, params, proj, tokens: np.ndarray,
+           prompt_len: int, quant: Optional[str] = None, q_chunk: int = 256):
+    """Final normed hidden states (T, d) in float32 for one sequence
+    through ``arch.hidden``; ``tokens`` is padded to a multiple of
+    ``q_chunk`` and of the prefill tile. Positions past the real sequence
+    are ignored by the caller."""
+    _CONFS[conf["name"]] = (arch, conf)
     return _hidden(params, proj, jnp.asarray(tokens, jnp.int32),
                    jnp.int32(prompt_len), conf_key=conf["name"],
                    quant=quant, q_chunk=q_chunk)
@@ -239,7 +177,8 @@ def _logits(table, h, *, quant=None):
 def _gap_of(logits, toks):
     best = jnp.max(logits, axis=-1)
     got = jnp.take_along_axis(logits, toks[:, None], axis=-1)[:, 0]
-    return best - got
+    top2 = jax.lax.top_k(logits, 2)[0]
+    return best - got, top2[:, 0] - top2[:, 1]
 
 
 def _padded(x: np.ndarray, block: int = 128) -> jax.Array:
@@ -251,10 +190,12 @@ def _padded(x: np.ndarray, block: int = 128) -> jax.Array:
 
 def served_gaps(table, h, positions: np.ndarray, toks: np.ndarray):
     """Reference best logit minus the reference logit of each served
-    token, at the positions whose logits chose them."""
+    token, at the positions whose logits chose them; and the reference's
+    best minus its second-best logit there (its margin)."""
     lg = _logits(table, h[_padded(positions)])
-    gap = _gap_of(lg, _padded(np.asarray(toks, np.int32)))
-    return np.asarray(gap)[:len(positions)]
+    gap, margin = _gap_of(lg, _padded(np.asarray(toks, np.int32)))
+    n = len(positions)
+    return np.asarray(gap)[:n], np.asarray(margin)[:n]
 
 
 def control_choice(table, h_ctrl, positions: np.ndarray, quant: str):
@@ -269,51 +210,11 @@ def control_choice(table, h_ctrl, positions: np.ndarray, quant: str):
 # ---------------------------------------------------------------------------
 
 
-def _capture(conf, params, tokens):
-    """Post-RoPE q (B,T,KV,G,D) and k (B,T,KV,D) of every layer, float32,
-    plain attention (no AQUA) — the calibration pass."""
-    a = conf["serve"]
-    eps = float(conf["rms_norm_eps"])
-    theta = float(conf["rope_theta"])
-    b, t = tokens.shape
-    positions = jnp.arange(t, dtype=jnp.int32)
-    x = params["embed"]["table"][tokens].astype(jnp.float32)
-    hd = conf["head_dim"]
-
-    def body(x, layer):
-        p = jax.tree.map(lambda w: w.astype(jnp.float32), layer)
-        at = p["attn"]
-        h = _rms(x, p["ln1"], eps)
-        q = jnp.einsum("btm,mkgd->btkgd", h, at["wq"], precision=HI)
-        k = jnp.einsum("btm,mkd->btkd", h, at["wk"], precision=HI)
-        v = jnp.einsum("btm,mkd->btkd", h, at["wv"], precision=HI)
-        if a["qkv_bias"]:
-            q, k, v = q + at["bq"], k + at["bk"], v + at["bv"]
-        if a["qk_norm"]:
-            q = _rms(q, at["q_norm"], eps)
-            k = _rms(k, at["k_norm"], eps)
-        rope = jax.vmap(lambda z: _rope(z, positions, theta))
-        q, k = rope(q), rope(k)
-        s = jnp.einsum("bskgd,btkd->bkgst", q, k, precision=HI) / hd ** 0.5
-        s = jnp.where(positions[None, :] <= positions[:, None], s, NEG_INF)
-        w = jax.nn.softmax(s, axis=-1)
-        o = jnp.einsum("bkgst,btkd->bskgd", w, v, precision=HI)
-        x = x + jnp.einsum("bskgd,kgdm->bsm", o, at["wo"], precision=HI)
-        h = _rms(x, p["ln2"], eps)
-        ffn = p["ffn"]
-        up = jax.nn.silu(jnp.einsum("btm,mf->btf", h, ffn["w1"],
-                                    precision=HI)) \
-            * jnp.einsum("btm,mf->btf", h, ffn["w3"], precision=HI)
-        x = x + jnp.einsum("btf,fm->btm", up, ffn["w2"], precision=HI)
-        return x, (q, k)
-    _, (qs, ks) = jax.lax.scan(body, x, params["layers"])
-    return qs, ks
-
-
 @functools.partial(jax.jit, static_argnames=("conf_key",))
 def _grams(params, tokens, *, conf_key):
-    conf = _CONFS[conf_key]
-    qs, ks = _capture(conf, params, tokens)        # (L,B,T,KV,G,D), (L,B,T,KV,D)
+    arch, conf = _CONFS[conf_key]
+    # (L, B, T, KV, G, D) and (L, B, T, KV, D)
+    qs, ks = arch.capture(conf, params, tokens)
     n, b, t, kvh, g, d = qs.shape
     qm = qs.transpose(0, 3, 1, 2, 4, 5).reshape(n, kvh, b * t * g, d)
     km = ks.transpose(0, 3, 1, 2, 4).reshape(n, kvh, b * t, d)
@@ -330,9 +231,10 @@ def corpus_tokens(path: str, vocab: int, rows: int, seq: int) -> np.ndarray:
     return np.stack([ids[s:s + seq] % vocab for s in starts]).astype(np.int32)
 
 
-def calibrate(conf: dict, params, tokens: np.ndarray) -> jax.Array:
-    """Projections (L, KV, D, D) float32, columns in descending variance."""
-    _CONFS[conf["name"]] = conf
+def calibrate(arch, conf: dict, params, tokens: np.ndarray) -> jax.Array:
+    """Projections (L, KV, D, D) float32, columns in descending variance,
+    from ``arch.capture``."""
+    _CONFS[conf["name"]] = (arch, conf)
     grams = np.asarray(_grams(params, jnp.asarray(tokens),
                               conf_key=conf["name"]), np.float64)
     _, vecs = np.linalg.eigh(grams)

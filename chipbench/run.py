@@ -4,7 +4,8 @@
 
 The cell's configuration, traffic mix, lanes and limits are files found by
 name (see ``chipbench/spec.py``). One run: refuse to run without a TPU;
-make the weights on the device from the seed and calibrate the AQUA
+make the weights on the device from the seed (the configuration's
+architecture module, ``chipbench/arch/``) and calibrate the AQUA
 projections (``chipbench/reference.py``); build the program's
 continuous-batching engine; admit the first wave and warm every shape the
 cell uses; measure for ``--seconds`` (``chipbench/drive.py``); read the
@@ -35,7 +36,7 @@ sys.path[:0] = [str(REPO), str(REPO / "src")]
 
 from chipbench import check, drive, reference, spec, trace, traffic  # noqa: E402
 from chipbench.record import Run  # noqa: E402
-from chipbench.yardstick import (CompileClock, Shapes, chip_peaks,  # noqa: E402
+from chipbench.yardstick import (CompileClock, chip_peaks,  # noqa: E402
                                  tpu_devices)
 
 CACHE_DIR = REPO / "chipbench" / ".jax_cache"
@@ -79,17 +80,17 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float,
     devices = tpu_devices(cell.chips) if require_tpu else jax.devices()[:1]
     dev = devices[0]
     clock = CompileClock(time.perf_counter)
-    conf, mix, cp = cell.config, cell.traffic, cell.params
+    arch, conf, mix, cp = cell.arch, cell.config, cell.traffic, cell.params
     lanes, max_seq = cp["lanes"], cp["max_seq"]
 
     marks = [("jax", time.perf_counter())]
-    cfg = spec.model_config(conf)
+    cfg = arch.program_config(conf)
     key = reference.weights_key(seed)
-    params = jax.jit(lambda k: reference.init_params(conf, k))(key)
+    params = jax.jit(lambda k: arch.init_params(conf, k))(key)
     check_layout(params, build_model(cfg), key)
     jax.block_until_ready(params)
     marks.append(("weights", time.perf_counter()))
-    proj = reference.calibrate(conf, params, reference.corpus_tokens(
+    proj = reference.calibrate(arch, conf, params, reference.corpus_tokens(
         str(CORPUS), conf["vocab_size"], **conf["calibration"]))
     marks.append(("calibration", time.perf_counter()))
     eng = drive.build_engine(cfg, params, proj, lanes, max_seq, conf, mix)
@@ -135,8 +136,8 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float,
 
     prompts = {p.uid: p.tokens for p in planned}
     sample = check.pick(win.served, win.finished, cp["check"], seed)
-    readings = check.compare(conf, params, proj, prompts, win.served, sample,
-                             max_seq, control=control)
+    readings = check.compare(arch, conf, params, proj, prompts, win.served,
+                             sample, max_seq, control=control)
     print(f"chipbench: compared {readings.tokens} served tokens of requests "
           f"{readings.requests} in {readings.seconds:.1f}s: widest gap "
           f"{readings.number('widest_gap')!r}, mean gap "
@@ -150,7 +151,7 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float,
           file=sys.stderr)
     ok = ok and fifo and not events
 
-    run = Run(lanes=lanes, shapes=Shapes.from_config(conf),
+    run = Run(lanes=lanes, shapes=arch.shapes(conf),
               peaks=chip_peaks(dev.device_kind) if require_tpu
               else chip_peaks("TPU v5 lite"),
               window=win, prompt_len={p.uid: len(p.tokens) for p in planned},

@@ -5,13 +5,20 @@ files behind those names live under ``chipbench/``:
 
 - ``configs/<config>.json``: the model configuration as it is run (the
   path is the configuration's ``file`` entry in ``BENCHMARK.json``);
+- ``arch/<architecture>.py``: the block that the configuration's
+  ``architecture`` names: ``program_config(conf)``, the program's
+  ``ModelConfig``; ``init_params(conf, key)``, the weights from the seed in
+  the program's layout; ``hidden`` and ``capture``, the float32 reference
+  over every layer and its calibration pass; ``shapes(conf)``, the sizes
+  behind ``step_mfu`` and the rooflines;
 - ``traffic/<traffic>.json``: the traffic mix's parameters;
 - ``cells/<cell>.json``: the cell's lanes, cache length and the limits of
   its output check;
 - ``metrics/<metric>.py``: one reader per metric, with a ``read(run)``
   function that returns a number or None.
 
-A cell, a mix or a metric is added by adding files; nothing here changes.
+A cell, a mix, a metric or a block shape is added by adding files; nothing
+here changes.
 """
 from __future__ import annotations
 
@@ -19,6 +26,7 @@ import dataclasses
 import importlib.util
 import json
 from pathlib import Path
+from types import ModuleType
 from typing import Callable, Dict, List
 
 
@@ -34,6 +42,7 @@ class Cell:
     name: str
     chips: int
     config: dict          # configs/<config>.json
+    arch: ModuleType      # arch/<architecture>.py
     traffic: dict         # traffic/<traffic>.json
     params: dict          # cells/<cell>.json
     end_to_end: List[Metric]
@@ -45,16 +54,26 @@ def _load_json(path: Path) -> dict:
         return json.load(f)
 
 
-def load_reader(root: Path, name: str) -> Callable:
-    """``read`` of ``chipbench/metrics/<name>.py`` under ``root``."""
-    path = root / "chipbench" / "metrics" / f"{name}.py"
+def _load_module(root: Path, kind: str, name: str) -> ModuleType:
+    """``chipbench/<kind>/<name>.py`` under ``root``, as a module."""
+    path = root / "chipbench" / kind / f"{name}.py"
     spec = importlib.util.spec_from_file_location(
-        f"chipbench_metric_{name.replace('.', '_').replace('-', '_')}", path)
+        f"chipbench_{kind}_{name.replace('.', '_').replace('-', '_')}", path)
     if spec is None:
-        raise FileNotFoundError(f"no reader for metric {name!r} at {path}")
+        raise FileNotFoundError(f"no {kind} module {name!r} at {path}")
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def load_reader(root: Path, name: str) -> Callable:
+    """``read`` of ``chipbench/metrics/<name>.py`` under ``root``."""
+    return _load_module(root, "metrics", name).read
+
+
+def load_arch(root: Path, name: str) -> ModuleType:
+    """The architecture module ``chipbench/arch/<name>.py`` under ``root``."""
+    return _load_module(root, "arch", name)
 
 
 def _metrics(root: Path, entries: list, cell: str) -> List[Metric]:
@@ -78,32 +97,8 @@ def load_cell(root: Path, name: str) -> Cell:
     traffic = _load_json(root / "chipbench" / "traffic" / f"{w['traffic']}.json")
     params = _load_json(root / "chipbench" / "cells" / f"{name}.json")
     return Cell(name=name, chips=int(w["chips"]), config=config,
+                arch=load_arch(root, config["architecture"]),
                 traffic=traffic, params=params,
                 end_to_end=_metrics(root, bench["end_to_end"], name),
                 per_layer=_metrics(root, bench["per_layer"], name))
 
-
-def model_config(conf: dict):
-    """The program's ``ModelConfig`` for a configuration file (published
-    key names, plus the serving and AQUA settings it states)."""
-    from repro.configs.base import AquaConfig, AttentionConfig, ModelConfig
-    serve, aqua = conf["serve"], conf["aqua"]
-    attention = AttentionConfig(
-        num_heads=conf["num_attention_heads"],
-        num_kv_heads=conf["num_key_value_heads"],
-        head_dim=conf["head_dim"], qk_norm=serve["qk_norm"],
-        qkv_bias=serve["qkv_bias"], rope_theta=float(conf["rope_theta"]),
-        backend=serve["backend"])
-    return ModelConfig(
-        name=conf["name"], family="dense",
-        num_layers=conf["num_hidden_layers"], d_model=conf["hidden_size"],
-        d_ff=conf["intermediate_size"], vocab_size=conf["vocab_size"],
-        attention=attention, norm_eps=float(conf["rms_norm_eps"]),
-        tie_embeddings=bool(conf["tie_word_embeddings"]),
-        act=conf["hidden_act"], dtype=serve["dtype"],
-        param_dtype=serve["param_dtype"], remat=False,
-        aqua=AquaConfig(k_ratio=aqua["k_ratio"],
-                        block_dims=aqua["block_dims"],
-                        prefill_q_blk=aqua["prefill_q_blk"],
-                        prefill_k_blk=aqua["prefill_k_blk"],
-                        decode_seq_blk=aqua["decode_seq_blk"]))

@@ -6,12 +6,13 @@ import pytest
 from chipbench import spec
 from chipbench.drive import Unit, Window, itl_gaps, ttfts
 from chipbench.record import Run
+from chipbench.tests import tiny
 from chipbench.tests.conftest import ROOT
-from chipbench.yardstick import (Shapes, chip_peaks, decode_token_flops,
+from chipbench.yardstick import (chip_peaks, decode_token_flops,
                                  prefill_flops)
 
-SHAPES = Shapes(layers=2, d_model=64, d_ff=128, vocab=512, heads=4,
-                kv_heads=2, head_dim=32, k_dims=24)
+SHAPES = spec.load_arch(ROOT, tiny.CONFIG["architecture"]).shapes(
+    tiny.CONFIG)
 
 
 def read(name, run):

@@ -3,9 +3,10 @@ chip. A sound run is correct; the float8 control and a broken timed path
 are not; without a TPU the command prints nothing and fails."""
 import json
 
+import numpy as np
 import pytest
 
-from chipbench import drive, faults, run
+from chipbench import check, drive, faults, run
 from chipbench.tests import tiny
 
 SEED = 2 ** 31 + 11
@@ -67,6 +68,32 @@ def test_broken_step_is_not_correct(tree, monkeypatch, fault):
     res = run_tiny(tree)
     assert res["correct"] is False
     assert any(v["value"] > v["limit"] for v in res["check"].values())
+
+
+def test_mean_gap_in_doubt_separates(tmp_path, monkeypatch):
+    """The same checks for a cell that compares the mean gap in doubt: a
+    sound run is correct, the float8 control and each fault are not."""
+    check = dict(tiny.CELL["check"], limits=tiny.DOUBT_LIMITS)
+    tree = (tmp_path, tiny.make_tree(tmp_path, cell={"check": check}))
+    res = run_tiny(tree, control=True)
+    assert res["correct"] is True
+    assert res["control"]["correct"] is False
+    build = drive.build_engine
+    for fault in faults.FAULTS:
+        monkeypatch.setattr(drive, "build_engine", faults.broken(
+            build, fault, tiny.CONFIG["vocab_size"]))
+        res = run_tiny(tree)
+        assert res["correct"] is False, fault
+        assert res["check"]["mean_gap_in_doubt"]["value"] \
+            > tiny.DOUBT_LIMITS["mean_gap_in_doubt"]
+
+
+def test_mean_gap_in_doubt_counts_doubtful_and_missed_positions():
+    gaps = np.array([0.0, 0.0, 0.0, 0.3, 0.1])
+    margins = np.array([0.1, 2.0, 3.0, 4.0, 0.2])
+    # counted: the first (in doubt), the fourth (missed), the fifth (both)
+    assert check.mean_gap_in_doubt(gaps, margins) == pytest.approx(0.4 / 3)
+    assert check.mean_gap_in_doubt(np.zeros(3), np.full(3, 9.0)) == 0.0
 
 
 def test_refuses_to_run_without_a_tpu(capsys):

@@ -34,7 +34,7 @@ def test_cell_resolves_by_name(name):
 @pytest.mark.parametrize("name", CELLS)
 def test_model_config_matches_the_file(name):
     cell = spec.load_cell(ROOT, name)
-    cfg = spec.model_config(cell.config)
+    cfg = cell.arch.program_config(cell.config)
     c = cell.config
     assert (cfg.num_layers, cfg.d_model, cfg.d_ff, cfg.vocab_size) == (
         c["num_hidden_layers"], c["hidden_size"], c["intermediate_size"],
@@ -114,8 +114,15 @@ def test_traffic_draws_lengths_from_the_seed(name):
     lanes, mix = cell.params["lanes"], cell.traffic
     runs = [traffic.closed_loop(mix, lanes, s, 1000)
             for s in (3, 2 ** 31 + 7)]
-    firsts = [sorted(len(p.tokens) for p in r[:lanes]) for r in runs]
-    assert firsts[0] != firsts[1], "every seed serves the same prompts"
+    lengths = [[(len(p.tokens), p.max_new) for p in r] for r in runs]
+    if "length_seed" in mix:
+        # the lengths come from the mix's own seed: every run serves the
+        # same lengths in the same order, and the seed draws the tokens
+        assert lengths[0] == lengths[1]
+        assert any((a.tokens != b.tokens).any() for a, b in zip(*runs))
+    else:
+        firsts = [sorted(n for n, _ in ls[:lanes]) for ls in lengths]
+        assert firsts[0] != firsts[1], "every seed serves the same prompts"
     (lo, hi), = mix["prompt_tokens"].values()
     for r in runs:
         # a wave takes one length from each of the lanes' strata
@@ -127,13 +134,7 @@ def test_traffic_draws_lengths_from_the_seed(name):
                        for i, n in enumerate(got))
 
 
-# cells whose window admits requests (in the long mix nothing completes
-# in a window, so only the first wave, in set-up, ever admits)
-ADMITTING = [c for c in CELLS if spec.load_cell(ROOT, c).traffic[
-    "open_after_completions_per_lane"] > 0]
-
-
-@pytest.mark.parametrize("name", ADMITTING)
+@pytest.mark.parametrize("name", CELLS)
 def test_first_wave_compiles_every_prefill_the_window_uses(name):
     """Set-up admits the first wave; every later admission has to reuse one
     of its prompt buckets, or it would compile inside the window."""

@@ -1,20 +1,21 @@
 """The operations and bytes behind step_mfu and the AQUA rooflines, at the
-three cells' shapes, against values worked by hand."""
+configurations' shapes, against values worked by hand."""
 import json
 
 import pytest
 
+from chipbench import spec
 from chipbench.tests.conftest import ROOT
-from chipbench.yardstick import (CHIP_PEAKS, Shapes, active_params,
-                                 aqua_decode_cost, aqua_prefill_cost,
-                                 attention_flops, chip_peaks,
-                                 decode_token_flops, least_seconds,
-                                 prefill_flops, round_k_dims)
+from chipbench.yardstick import (CHIP_PEAKS, Shapes, aqua_decode_cost,
+                                 aqua_prefill_cost, attention_flops,
+                                 chip_peaks, decode_token_flops,
+                                 least_seconds, prefill_flops, round_k_dims)
 
 
 def shapes(name):
     with open(ROOT / "chipbench" / "configs" / f"{name}.json") as f:
-        return Shapes.from_config(json.load(f))
+        conf = json.load(f)
+    return spec.load_arch(ROOT, conf["architecture"]).shapes(conf)
 
 
 def test_k_dims_round_to_whole_blocks():
@@ -29,9 +30,9 @@ def test_qwen3_0_6b_counts():
     # per layer: q,k,v 1024*128*(16+8+8) + o 16*128*1024 + mlp 3*1024*3072
     #          = 4,194,304 + 2,097,152 + 9,437,184 = 15,728,640
     # 28 layers + unembedding 1024*151,936 = 155,582,464
-    assert active_params(s) == 28 * 15_728_640 + 155_582_464 == 595_984_384
+    assert s.active == 28 * 15_728_640 + 155_582_464 == 595_984_384
     # one query over 8192 keys: 2 * 28 layers * 16 heads * 8192 * (96 + 128)
-    assert attention_flops(s, 8192) == 1_644_167_168
+    assert attention_flops(s, s.decode_keys(8192)) == 1_644_167_168
     # decode kernel bytes at 8192: 28 * (8 KV heads * 8192 * 224
     #   + q and out 2 * 16 * 128) * 2 B = 28 * 14,684,160 * 2
     assert aqua_decode_cost(s, 8192) == (1_644_167_168, 822_312_960)
@@ -46,15 +47,30 @@ def test_qwen3_0_6b_counts():
 
 def test_mha_counts():
     # Qwen1.5-4B's layers at 10 of its 40: one query head per KV head
-    s = Shapes(layers=10, d_model=2560, d_ff=6912, vocab=151_936, heads=20,
-               kv_heads=20, head_dim=128, k_dims=96)
+    s = shapes("qwen1.5-4b-10l")
     # per layer: 2560*128*(20+20+20) + 20*128*2560 + 3*2560*6912
     #          = 19,660,800 + 6,553,600 + 53,084,160 = 79,298,560
-    assert active_params(s) == 10 * 79_298_560 + 2560 * 151_936 \
+    assert s.active == 10 * 79_298_560 + 2560 * 151_936 \
         == 1_181_941_760
     # 2 * 10 * 20 * 8192 * 224 FLOPs; 10 * (20 * 8192 * 224 + 2 * 20 * 128)
     # * 2 B of bytes
     assert aqua_decode_cost(s, 8192) == (734_003_200, 734_105_600)
+
+
+def test_windowed_layers_count_only_their_window():
+    # one full layer and one that sees the last 100 keys
+    s = Shapes(active=1000, unembed=100, windows=(None, 100), heads=4,
+               kv_heads=2, head_dim=32, k_dims=24)
+    assert s.layers == 2
+    assert s.decode_keys(50) == 100 and s.decode_keys(300) == 400
+    # prefill of 300: full 300*301/2 = 45,150; windowed: tokens 1-100 see
+    # 1..100 (5,050), the other 200 see 100 each (20,000)
+    assert s.causal_keys(300) == 45_150 + 25_050
+    assert s.causal_keys(80) == 2 * 80 * 81 / 2
+    assert aqua_decode_cost(s, 300) == (
+        2.0 * 4 * 400 * 56, float((2 * 400 * 56 + 2 * 2 * 4 * 32) * 2))
+    assert prefill_flops(s, 300) == (2.0 * 900 * 300 + 2.0 * 100
+                                     + 2.0 * 4 * 70_200 * 56)
 
 
 def test_least_time_is_the_larger_bound():

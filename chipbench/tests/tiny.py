@@ -10,6 +10,7 @@ HERE = Path(__file__).resolve().parents[1]
 
 CONFIG = {
     "name": "tiny", "source": "test", "model_type": "qwen3",
+    "architecture": "dense",
     "hidden_size": 64, "intermediate_size": 128, "num_hidden_layers": 2,
     "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 32,
     "vocab_size": 512, "rope_theta": 10000.0, "rms_norm_eps": 1e-6,
@@ -36,19 +37,26 @@ CELL = {"lanes": 2, "max_seq": 80,
         "check": {"from": "served", "tokens": 400, "max_requests": 64,
                   "limits": {"mean_gap": 0.015}}}
 
+# the limit lies between the served path's mean gap in doubt (at most
+# 0.0075 over eight seeds) and the float8 control's (at least 0.064) at
+# this size; state_unchanged reads 0.31 and token_altered 2.3
+DOUBT_LIMITS = {"mean_gap_in_doubt": 0.025}
+
 
 def make_tree(root: Path, config=None, traffic=None, cell=None,
               name: str = "tiny.chat") -> str:
     """Write BENCHMARK.json and the cell's files under ``root``; return
-    the cell's name. The metric readers are copied from this benchmark."""
+    the cell's name. The metric readers and the architecture modules are
+    copied from this benchmark."""
     conf = dict(CONFIG, **(config or {}))
     cfg_name, mix_name = name.split(".", 1)
     conf["name"] = cfg_name
     (root / "chipbench" / "configs").mkdir(parents=True, exist_ok=True)
     (root / "chipbench" / "traffic").mkdir(parents=True, exist_ok=True)
     (root / "chipbench" / "cells").mkdir(parents=True, exist_ok=True)
-    if not (root / "chipbench" / "metrics").exists():
-        shutil.copytree(HERE / "metrics", root / "chipbench" / "metrics")
+    for kind in ("metrics", "arch"):
+        if not (root / "chipbench" / kind).exists():
+            shutil.copytree(HERE / kind, root / "chipbench" / kind)
     (root / "chipbench" / "configs" / f"{cfg_name}.json").write_text(
         json.dumps(conf))
     (root / "chipbench" / "traffic" / f"{mix_name}.json").write_text(

@@ -7,7 +7,12 @@ of ``lanes`` lengths is the distribution's quantile at (i + u) / lanes,
 with u uniform in [0, 1) and drawn anew for every length, so every wave
 spans the whole distribution while every seed serves other lengths. The
 seed also orders the lengths within a wave, pairs prompts with outputs,
-and draws the token ids. Every arrival is at 0: a lane frees when a
+and draws the token ids. A mix that states ``length_seed`` draws its
+lengths, their order and their pairing from that seed instead, so every
+run serves the same lengths in the same order and the seed draws only the
+token ids: its window then holds the same work on every seed, where a
+few long admissions in a window would otherwise set the rate. Every
+arrival is at 0: a lane frees when a
 request completes, and the engine admits the next request of the FIFO
 backlog into it, so the k-th completion releases the request at queue
 position ``lanes + k - 1``.
@@ -52,10 +57,12 @@ def seed_rng(seed: int, stream: int) -> np.random.Generator:
 def closed_loop(mix: dict, lanes: int, seed: int, vocab: int) -> List[Planned]:
     """The backlog of one run, in queue order (uid = queue position)."""
     rng = seed_rng(seed, 1)
+    sizes = (rng if "length_seed" not in mix
+             else seed_rng(int(mix["length_seed"]), 2))
     out: List[Planned] = []
     for _ in range(BACKLOG_WAVES):
-        p = rng.permutation(stratified(mix["prompt_tokens"], lanes, rng))
-        o = rng.permutation(stratified(mix["output_tokens"], lanes, rng))
+        p = sizes.permutation(stratified(mix["prompt_tokens"], lanes, sizes))
+        o = sizes.permutation(stratified(mix["output_tokens"], lanes, sizes))
         for pl, ol in zip(p, o):
             toks = rng.integers(0, vocab, size=(int(pl),), dtype=np.int32)
             out.append(Planned(uid=len(out), tokens=toks, max_new=int(ol)))

@@ -4,7 +4,8 @@ check, and the operations and bytes that the model and its AQUA kernels need.
 Copied here so that a change to the program cannot move them:
 - ``CHIP_PEAKS`` / ``chip_peaks`` from ``src/repro/launch/mesh.py``;
 - ``CompileClock`` and ``tpu_devices`` from ``chip_smoke.py``;
-- ``active_params`` from ``benchmarks/roofline.py`` (dense family only).
+- the per-token parameter count of ``benchmarks/roofline.py``, which each
+  architecture module gives as ``Shapes.active``.
 
 Every count is of what the algorithm needs, worked out from shapes and the
 real context of each token, independent of how a kernel walks its grid.
@@ -98,28 +99,34 @@ def tpu_devices(chips: int):
 
 @dataclasses.dataclass(frozen=True)
 class Shapes:
-    """The sizes the counts need, from a configuration file."""
+    """The sizes the counts need. An architecture module builds them from
+    its configuration file (``arch/<architecture>.py``, ``shapes``)."""
 
-    layers: int
-    d_model: int
-    d_ff: int
-    vocab: int
-    heads: int
+    active: int           # parameters a token multiplies: layers + unembedding
+    unembed: int          # the unembedding's share of ``active``
+    windows: tuple        # per attention layer: keys it may see, None = all
+    heads: int            # query heads of an attention layer
     kv_heads: int
     head_dim: int
     k_dims: int           # dims the |q| selection keeps (score product)
 
-    @classmethod
-    def from_config(cls, conf: dict) -> "Shapes":
-        d = conf["head_dim"]
-        return cls(layers=conf["num_hidden_layers"],
-                   d_model=conf["hidden_size"],
-                   d_ff=conf["intermediate_size"],
-                   vocab=conf["vocab_size"],
-                   heads=conf["num_attention_heads"],
-                   kv_heads=conf["num_key_value_heads"], head_dim=d,
-                   k_dims=round_k_dims(d, conf["aqua"]["k_ratio"],
-                                       conf["aqua"]["block_dims"]))
+    @property
+    def layers(self) -> int:
+        return len(self.windows)
+
+    def decode_keys(self, context: float) -> float:
+        """Keys one query over ``context`` keys attends, over all layers."""
+        return sum(context if w is None else min(context, w)
+                   for w in self.windows)
+
+    def causal_keys(self, prompt: int) -> float:
+        """Keys a causal prefill of ``prompt`` tokens attends (token i sees
+        i + 1, or its window), over all layers."""
+        def one(w):
+            if w is None or prompt <= w:
+                return prompt * (prompt + 1) / 2.0
+            return w * (w + 1) / 2.0 + (prompt - w) * w
+        return sum(one(w) for w in self.windows)
 
 
 def round_k_dims(d: int, k_ratio: float, block_dims: int) -> int:
@@ -130,52 +137,42 @@ def round_k_dims(d: int, k_ratio: float, block_dims: int) -> int:
     return min(k, d)
 
 
-def active_params(s: Shapes) -> float:
-    """Per-token parameters of a dense model: attention and gated MLP of
-    every layer plus the unembedding (embeddings excluded)."""
-    attn = (s.d_model * s.head_dim * (s.heads + 2 * s.kv_heads)
-            + s.heads * s.head_dim * s.d_model)
-    return s.layers * (attn + 3 * s.d_model * s.d_ff) + s.d_model * s.vocab
-
-
-def attention_flops(s: Shapes, context: float) -> float:
-    """Attention FLOPs of one query token over ``context`` keys in every
-    layer: the score product on the ``k_dims`` selected dims, the value
-    product on all ``head_dim``."""
-    return 2.0 * s.layers * s.heads * context * (s.k_dims + s.head_dim)
+def attention_flops(s: Shapes, keys: float) -> float:
+    """Attention FLOPs over ``keys`` keys summed over layers: the score
+    product on the ``k_dims`` selected dims, the value product on all
+    ``head_dim``."""
+    return 2.0 * s.heads * keys * (s.k_dims + s.head_dim)
 
 
 def decode_token_flops(s: Shapes, context: int) -> float:
     """Model FLOPs of one decoded token whose query sees ``context`` keys."""
-    return 2.0 * active_params(s) + attention_flops(s, context)
+    return 2.0 * s.active + attention_flops(s, s.decode_keys(context))
 
 
 def prefill_flops(s: Shapes, prompt: int) -> float:
     """Model FLOPs of a prefill of ``prompt`` tokens: every token through
     the layers, the unembedding once (the first token's logits), and
     causal attention (token i sees i + 1 keys)."""
-    unembed = s.d_model * s.vocab
-    causal_keys = prompt * (prompt + 1) / 2.0
-    return (2.0 * (active_params(s) - unembed) * prompt + 2.0 * unembed
-            + attention_flops(s, causal_keys))
+    return (2.0 * (s.active - s.unembed) * prompt + 2.0 * s.unembed
+            + attention_flops(s, s.causal_keys(prompt)))
 
 
 def aqua_decode_cost(s: Shapes, context: int) -> tuple:
     """(FLOPs, bytes) the AQUA decode kernel needs for one lane's query in
     every layer: each KV head's selected K-hat dims and its V over the real
     context, read once; q and the output written once, in bf16."""
-    flops = attention_flops(s, context)
-    kv = s.kv_heads * context * (s.k_dims + s.head_dim)
+    keys = s.decode_keys(context)
+    kv = s.kv_heads * keys * (s.k_dims + s.head_dim)
     qo = 2 * s.heads * s.head_dim
-    return flops, float(s.layers * (kv + qo) * BF16_BYTES)
+    return (attention_flops(s, keys),
+            float((kv + s.layers * qo) * BF16_BYTES))
 
 
 def aqua_prefill_cost(s: Shapes, prompt: int) -> tuple:
     """(FLOPs, bytes) the AQUA prefill kernel needs for one prompt in every
     layer: causal half of the score and value products; q, selected K-hat,
     V and the output each moved once, in bf16."""
-    causal_keys = prompt * (prompt + 1) / 2.0
-    flops = attention_flops(s, causal_keys)
+    flops = attention_flops(s, s.causal_keys(prompt))
     moved = prompt * (s.heads * (s.k_dims + s.head_dim)
                       + s.kv_heads * (s.k_dims + s.head_dim))
     return flops, float(s.layers * moved * BF16_BYTES)
