@@ -15,6 +15,7 @@ work while it yields the tokens of one step.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Callable, Dict, List, Optional
 
 import numpy as np
@@ -48,19 +49,39 @@ class Window:
     labels: Dict[int, str]        # next() call number -> what it returned
 
 
-def build_engine(cfg, params, proj, lanes: int, max_seq: int, conf: dict,
-                 mix: dict):
+def mesh_shape(conf: dict) -> Optional[tuple]:
+    """The deployment's mesh over ("data", "model") that the
+    configuration's ``serve.mesh_shape`` states; None serves on one
+    device."""
+    shape = conf["serve"].get("mesh_shape")
+    return None if shape is None else tuple(int(n) for n in shape)
+
+
+def chips(conf: dict) -> int:
+    """Devices the configuration serves on: its mesh's size, else 1."""
+    return math.prod(mesh_shape(conf) or (1,))
+
+
+def serving_config(lanes: int, max_seq: int, conf: dict, mix: dict):
+    """The program's ``ServingConfig`` for a cell: its lanes, cache, paging
+    and, where the configuration states one, its mesh."""
     from repro.configs.base import CacheSpec, ServingConfig
-    from repro.core.calibration import AquaProjections
-    from repro.serving.engine import ContinuousBatchingEngine
     serve = conf["serve"]
-    scfg = ServingConfig(
+    return ServingConfig(
         max_lanes=lanes, max_seq=max_seq, temperature=mix["temperature"],
         prompt_bucket=mix["prompt_bucket"],
         cache=CacheSpec(page_size=serve["page_size"],
-                        prefix_sharing=serve["prefix_sharing"]))
-    return ContinuousBatchingEngine(cfg, params, AquaProjections(p=proj),
-                                    serving=scfg)
+                        prefix_sharing=serve["prefix_sharing"]),
+        mesh_shape=mesh_shape(conf))
+
+
+def build_engine(cfg, params, proj, lanes: int, max_seq: int, conf: dict,
+                 mix: dict):
+    from repro.core.calibration import AquaProjections
+    from repro.serving.engine import ContinuousBatchingEngine
+    return ContinuousBatchingEngine(
+        cfg, params, AquaProjections(p=proj),
+        serving=serving_config(lanes, max_seq, conf, mix))
 
 
 def requests(planned, temperature: float):

@@ -1,7 +1,11 @@
 """Least time over device time of the AQUA prefill kernel, for the
 window's admissions: causal half of each real prompt (yardstick.
 aqua_prefill_cost) over the chip's peaks, against the kernel's summed
-events in the trace."""
+events in the trace.
+
+On a mesh both sides are chip-seconds: the least time is that of all the
+work at one chip's peaks, and ``trace.matching`` sums the kernel's events
+over every device, each device running its share of the heads."""
 from chipbench import trace
 from chipbench.yardstick import aqua_prefill_cost, least_seconds
 

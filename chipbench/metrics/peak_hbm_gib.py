@@ -1,4 +1,5 @@
-"""Peak device bytes in use after the window, in GiB (memory_stats)."""
+"""Peak device bytes in use after the window on the fullest of the cell's
+devices, in GiB (memory_stats)."""
 
 
 def read(run):

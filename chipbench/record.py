@@ -11,13 +11,14 @@ from chipbench.yardstick import ChipPeaks, Shapes
 @dataclasses.dataclass
 class Run:
     lanes: int
+    chips: int                      # devices the cell serves on
     shapes: Shapes
     peaks: ChipPeaks
     window: Window
     prompt_len: Dict[int, int]      # uid -> prompt tokens
     setup_s: float
     compiles_in_window: int
-    memory_peak_bytes: Optional[int]
+    memory_peak_bytes: Optional[int]  # the fullest device's peak
     trace: Optional[dict] = None    # chipbench.trace record (--trace 1)
 
     @property

@@ -3,14 +3,18 @@
     python3 chipbench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 The cell's configuration, traffic mix, lanes and limits are files found by
-name (see ``chipbench/spec.py``). One run: refuse to run without a TPU;
+name (see ``chipbench/spec.py``). One run: refuse to run without a TPU, or
+with fewer chips than the cell's ``chips``, which must be the size of the
+mesh its configuration states (``serve.mesh_shape``; none is one chip);
 make the weights on the device from the seed (the configuration's
-architecture module, ``chipbench/arch/``) and calibrate the AQUA
-projections (``chipbench/reference.py``); build the program's
-continuous-batching engine; admit the first wave and warm every shape the
-cell uses; measure for ``--seconds`` (``chipbench/drive.py``); read the
-peak device memory and free the program's cache; compare the served tokens
-with the float32 reference (``chipbench/check.py``); print one JSON line.
+architecture module, ``chipbench/arch/``), on a mesh straight into the
+engine's parameter shardings, and calibrate the AQUA projections
+(``chipbench/reference.py``); build the program's continuous-batching
+engine, on that mesh where there is one; admit the first wave and warm
+every shape the cell uses; measure for ``--seconds``
+(``chipbench/drive.py``); read every device's peak memory and free the
+program's cache; compare the served tokens with the float32 reference
+(``chipbench/check.py``, on the same devices); print one JSON line.
 
 With ``--trace 0`` the line holds the cell's end-to-end metrics; with
 ``--trace 1`` the window runs under the profiler and the line holds the
@@ -37,7 +41,7 @@ sys.path[:0] = [str(REPO), str(REPO / "src")]
 from chipbench import check, drive, reference, spec, trace, traffic  # noqa: E402
 from chipbench.record import Run  # noqa: E402
 from chipbench.yardstick import (CompileClock, chip_peaks,  # noqa: E402
-                                 tpu_devices)
+                                 device_peaks, fullest, tpu_devices)
 
 CACHE_DIR = REPO / "chipbench" / ".jax_cache"
 CORPUS = REPO / "chipbench" / "data" / "calibration.txt"
@@ -65,6 +69,35 @@ def check_layout(params, model, key):
                            "benchmark's weights layout")
 
 
+def draw_weights(arch, conf: dict, key, mesh=None):
+    """The weights from the seed, made on the device in one jitted call.
+    Under a mesh every leaf is made in the shards the program's sharding
+    rules give it, so no device holds the whole tree; the values are those
+    of the one-device draw."""
+    import jax
+    init = lambda k: arch.init_params(conf, k)  # noqa: E731
+    if mesh is None:
+        return jax.jit(init)(key)
+    from repro.distributed.sharding import make_param_shardings
+    shardings = make_param_shardings(jax.eval_shape(init, key), mesh)
+    return jax.jit(init, out_shardings=shardings)(key)
+
+
+def check_plan(plan, backend: str, chips: int) -> None:
+    """The engine must serve the configured AQUA backend on the paged
+    cache through its kernel path: on one chip a plan whose only reason is
+    the absent mesh, on a mesh a mesh-native plan with no reason at all."""
+    from repro.core.dispatch import REASON_NO_MESH
+    ok = plan.backend == backend and plan.cache_layout == "paged"
+    if chips == 1:
+        ok = ok and plan.reasons == (REASON_NO_MESH,)
+    else:
+        ok = ok and plan.mesh_native and plan.reasons == ()
+    if not ok:
+        raise RuntimeError(f"the engine did not plan the kernel path on "
+                           f"{chips} chip(s): {plan}")
+
+
 def run_cell(root: Path, workload: str, seed: int, seconds: float,
              traced: bool, *, require_tpu: bool = True,
              t_start: float = T_START, control: bool = False,
@@ -73,20 +106,33 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float,
     when ``control``, the control's verdict under ``control``, and the raw
     ``readings`` when ``control`` or ``keep_readings``)."""
     cell = spec.load_cell(root, workload)
+    arch, conf, mix, cp = cell.arch, cell.config, cell.traffic, cell.params
+    if drive.chips(conf) != cell.chips:
+        raise RuntimeError(
+            f"{workload} asks for {cell.chips} chip(s), its configuration's "
+            f"mesh {drive.mesh_shape(conf)} holds {drive.chips(conf)}")
     configure_jax()
     import jax
-    from repro.core.dispatch import REASON_NO_MESH
     from repro.models import build_model
-    devices = tpu_devices(cell.chips) if require_tpu else jax.devices()[:1]
+    devices = (tpu_devices(cell.chips) if require_tpu
+               else jax.devices()[:cell.chips])
+    if len(devices) < cell.chips:
+        raise RuntimeError(f"{workload} needs {cell.chips} devices, JAX "
+                           f"found {len(devices)}")
     dev = devices[0]
     clock = CompileClock(time.perf_counter)
-    arch, conf, mix, cp = cell.arch, cell.config, cell.traffic, cell.params
     lanes, max_seq = cp["lanes"], cp["max_seq"]
+    mesh = None
+    if drive.mesh_shape(conf) is not None:
+        # the mesh the engine builds from its ServingConfig: the first
+        # ``chips`` devices, ("data", "model")
+        from repro.launch.mesh import make_serving_mesh
+        mesh = make_serving_mesh(drive.mesh_shape(conf))
 
     marks = [("jax", time.perf_counter())]
     cfg = arch.program_config(conf)
     key = reference.weights_key(seed)
-    params = jax.jit(lambda k: arch.init_params(conf, k))(key)
+    params = draw_weights(arch, conf, key, mesh)
     check_layout(params, build_model(cfg), key)
     jax.block_until_ready(params)
     marks.append(("weights", time.perf_counter()))
@@ -95,10 +141,7 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float,
     marks.append(("calibration", time.perf_counter()))
     eng = drive.build_engine(cfg, params, proj, lanes, max_seq, conf, mix)
     marks.append(("engine", time.perf_counter()))
-    plan = eng.dispatch_plan()
-    if (plan.backend != conf["serve"]["backend"] or plan.cache_layout != "paged"
-            or plan.reasons != (REASON_NO_MESH,)):
-        raise RuntimeError(f"the engine did not plan the kernel path: {plan}")
+    check_plan(eng.dispatch_plan(), conf["serve"]["backend"], cell.chips)
 
     planned = traffic.closed_loop(mix, lanes, seed, conf["vocab_size"])
     reqs = drive.requests(planned, mix["temperature"])
@@ -121,8 +164,8 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float,
     steps = [f"{n} {t - p:.1f}s" for (n, t), (_, p)
              in zip(marks, [(None, t_start)] + marks)]
     print(f"chipbench: setup phases: {', '.join(steps)}", file=sys.stderr)
-    stats = dev.memory_stats() or {}
-    peak = stats.get("peak_bytes_in_use")
+    peaks = device_peaks(devices)
+    peak = fullest(peaks)
     fifo = win.admission_order == list(range(len(win.admission_order)))
     events = eng.mesh_fallback_events()
     eng.last_state = eng.last_lanes = None
@@ -151,7 +194,7 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float,
           file=sys.stderr)
     ok = ok and fifo and not events
 
-    run = Run(lanes=lanes, shapes=arch.shapes(conf),
+    run = Run(lanes=lanes, chips=cell.chips, shapes=arch.shapes(conf),
               peaks=chip_peaks(dev.device_kind) if require_tpu
               else chip_peaks("TPU v5 lite"),
               window=win, prompt_len={p.uid: len(p.tokens) for p in planned},
@@ -164,7 +207,8 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float,
         if v is not None:
             metrics[m.name] = {"value": v, "unit": m.unit}
     device = {"platform": dev.platform, "kind": dev.device_kind,
-              "count": len(jax.devices()), "memory_peak_bytes": peak}
+              "count": len(jax.devices()), "memory_peak_bytes": peak,
+              "memory_peak_bytes_per_device": peaks}
     served_in_window = {uid for u in win.units for uid, _ in u.tokens}
     result = {"correct": bool(ok), "attempted": len(served_in_window),
               "failed": 0 if ok else len(sample),

@@ -9,7 +9,7 @@ from chipbench.record import Run
 from chipbench.tests import tiny
 from chipbench.tests.conftest import ROOT
 from chipbench.yardstick import (chip_peaks, decode_token_flops,
-                                 prefill_flops)
+                                 device_peaks, fullest, prefill_flops)
 
 SHAPES = spec.load_arch(ROOT, tiny.CONFIG["architecture"]).shapes(
     tiny.CONFIG)
@@ -30,8 +30,9 @@ def window(times, *, t_open, t_close, units=(), finished=None,
                   occupancy_close=occupancy[1], labels={})
 
 
-def run_of(win, lanes=2, prompt_len=None, trace=None):
-    return Run(lanes=lanes, shapes=SHAPES, peaks=chip_peaks("TPU v5 lite"),
+def run_of(win, lanes=2, prompt_len=None, trace=None, chips=1):
+    return Run(lanes=lanes, chips=chips, shapes=SHAPES,
+               peaks=chip_peaks("TPU v5 lite"),
                window=win, prompt_len=prompt_len or {},
                setup_s=12.5, compiles_in_window=0, memory_peak_bytes=2 ** 31,
                trace=trace)
@@ -102,10 +103,41 @@ def test_step_mfu_counts_decode_and_prefill_tokens():
     assert read("step_mfu", run) == pytest.approx(100 * want / (2.0 * 197e12))
 
 
+def test_step_mfu_divides_by_every_chip_of_the_cell():
+    units = [Unit("step", 1.5, [(0, 4), (1, 7)]), Unit("admit", 2.0, [(2, 0)])]
+    win = window({}, t_open=1.0, t_close=3.0, units=units)
+    prompts = {0: 40, 1: 50, 2: 30}
+    one = read("step_mfu", run_of(win, prompt_len=prompts))
+    four = read("step_mfu", run_of(win, prompt_len=prompts, chips=4))
+    assert four == pytest.approx(one / 4)
+
+
 def test_trace_readers_are_silent_without_a_trace():
     win = window({}, t_open=0, t_close=1)
     for name in ("decode_step_ms", "aqua_decode_roofline",
-                 "aqua_prefill_roofline", "device_idle_share"):
+                 "aqua_prefill_roofline", "device_idle_share",
+                 "collective_ms", "collective_exposed_ms"):
         assert read(name, run_of(win)) is None
     assert read("peak_hbm_gib", run_of(win)) == 2.0
     assert read("compiles_in_window", run_of(win)) == 0.0
+
+
+class FakeDevice:
+    def __init__(self, stats):
+        self.stats = stats
+
+    def memory_stats(self):
+        return self.stats
+
+
+def test_the_fullest_device_is_the_one_reported():
+    devs = [FakeDevice({"peak_bytes_in_use": n})
+            for n in (3 << 30, 7 << 30, 5 << 30, 6 << 30)]
+    peaks = device_peaks(devs)
+    assert peaks == [3 << 30, 7 << 30, 5 << 30, 6 << 30]
+    assert fullest(peaks) == 7 << 30
+    assert fullest([None, 2 << 30]) == 2 << 30
+    assert fullest(device_peaks([FakeDevice(None)])) is None
+    run = run_of(window({}, t_open=0, t_close=1))
+    run.memory_peak_bytes = fullest(peaks)
+    assert read("peak_hbm_gib", run) == 7.0

@@ -5,7 +5,7 @@ import re
 
 import pytest
 
-from chipbench import spec, traffic
+from chipbench import drive, spec, traffic
 from chipbench.tests import tiny
 from chipbench.tests.conftest import ROOT
 
@@ -19,7 +19,7 @@ def test_cell_resolves_by_name(name):
     cell = spec.load_cell(ROOT, name)
     w = next(w for w in BENCH["workloads"] if w["name"] == name)
     assert cell.config["name"] == w["config"]
-    assert cell.chips == w["chips"] == 1
+    assert cell.chips == w["chips"] == drive.chips(cell.config)
     assert set(cell.params) == {"lanes", "max_seq", "check"}
     assert cell.params["check"]["limits"], "a cell needs a limit"
     names = {m.name for m in cell.end_to_end}

@@ -43,8 +43,29 @@ CELL = {"lanes": 2, "max_seq": 80,
 DOUBT_LIMITS = {"mean_gap_in_doubt": 0.025}
 
 
+# the tiny block on a 1x4 mesh: heads that divide four ways (MHA, 4 KV
+# heads, G = 1), with q/k/v biases and an untied unembedding, as
+# qwen1.5-4b-tp4 has them
+MESH_CONFIG = dict(
+    CONFIG, num_key_value_heads=4, tie_word_embeddings=False,
+    serve=dict(CONFIG["serve"], qk_norm=False, qkv_bias=True,
+               mesh_shape=[1, 4]))
+
+# the mesh cell's limit lies between the served path's mean gap in doubt
+# (at most 0.0028 over ten seeds) and the float8 control's (at least
+# 0.0178) on the 1x4 mesh of host devices, with MESH_TRAFFIC and a 1 s
+# window
+MESH_CHECK = dict(CELL["check"], limits={"mean_gap_in_doubt": 0.007})
+
+# longer outputs for the mesh cell (and the cache to hold them), so that
+# its window holds decode steps in every lane and its backlog outlasts the
+# window on a fast host
+MESH_TRAFFIC = {"output_tokens": {"log_uniform": [8, 24]}}
+MESH_CELL = {"max_seq": 96, "check": MESH_CHECK}
+
+
 def make_tree(root: Path, config=None, traffic=None, cell=None,
-              name: str = "tiny.chat") -> str:
+              name: str = "tiny.chat", chips: int = 1) -> str:
     """Write BENCHMARK.json and the cell's files under ``root``; return
     the cell's name. The metric readers and the architecture modules are
     copied from this benchmark."""
@@ -68,7 +89,8 @@ def make_tree(root: Path, config=None, traffic=None, cell=None,
                          "file": f"chipbench/configs/{cfg_name}.json",
                          "reduced": [], "why": "test"}]
     bench["workloads"] = [{"name": name, "config": cfg_name,
-                           "traffic": mix_name, "chips": 1, "why": "test"}]
+                           "traffic": mix_name, "chips": chips,
+                           "why": "test"}]
     for m in bench["end_to_end"] + bench["per_layer"]:
         m.pop("workloads", None)
     (root / "BENCHMARK.json").write_text(json.dumps(bench))

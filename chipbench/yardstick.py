@@ -14,6 +14,7 @@ from __future__ import annotations
 
 import dataclasses
 import sys
+from typing import Optional
 
 BF16_BYTES = 2
 
@@ -90,6 +91,20 @@ def tpu_devices(chips: int):
               f"{len(devices)}", file=sys.stderr)
         raise SystemExit(2)
     return devices[:chips]
+
+
+def device_peaks(devices) -> list:
+    """``peak_bytes_in_use`` of each device's ``memory_stats()``, in the
+    order given; None where the backend reports none."""
+    return [(d.memory_stats() or {}).get("peak_bytes_in_use")
+            for d in devices]
+
+
+def fullest(peaks: list) -> Optional[int]:
+    """The largest of the devices' peaks: a cell is as large as its
+    fullest device. None where no device reports one."""
+    known = [p for p in peaks if p is not None]
+    return max(known) if known else None
 
 
 # ---------------------------------------------------------------------------
